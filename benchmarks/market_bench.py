@@ -416,6 +416,8 @@ def main() -> None:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     if args.seed is not None:
         os.environ["REPRO_BENCH_SEED"] = str(args.seed)
+    from repro import compile_cache
+    compile_cache.enable()
     lines = ["name,us_per_call,derived"]
     print(lines[0])
     for name, us, derived in run():

@@ -63,6 +63,10 @@ def main() -> None:
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.force_devices}").strip()
 
+    # after --force-devices: enabling the cache imports jax
+    from repro import compile_cache
+    compile_cache.enable()
+
     # The FULL spot-market policy benchmark and the serving benchmark are
     # NOT in this list: each is its own CLI (``python -m
     # benchmarks.market_bench`` / ``benchmarks.serving_bench``) with the

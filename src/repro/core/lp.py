@@ -69,11 +69,14 @@ _TOL = 1e-9
 _INF_UB = 1e30          # finite stand-in for +inf upper bounds
 _CHUNK_ITERS = 8        # default chunk length of the compacted driver
 
-# Pluggable Newton linear-system backends.  "xla" is the historical
-# jnp.linalg.solve (batched LU through lapack on CPU); "ref" is the
-# pure-jnp Cholesky oracle (kernels/ref.py); "pallas" is the blocked
-# batched-Cholesky Pallas kernel (kernels/batched_chol.py) compiled on
-# TPU and interpret-mode on CPU; "pallas-interpret" forces interpret mode
+# Pluggable Newton linear-system backends.  The normal matrix
+# A Theta^-1 A^T is SPD by construction (A has full row rank), so every
+# backend is a Cholesky solve.  "xla" is XLA's Cholesky + two triangular solves (the
+# default; compiles on every platform, float64 included); "ref" is the
+# pure-jnp Cholesky oracle (kernels/ref.py); "pallas" is the batched-
+# Cholesky Pallas kernel (kernels/batched_chol.py), compiled on TPU —
+# float32 only, so there it pairs with newton_dtype="float32" — and
+# interpret-mode on CPU; "pallas-interpret" forces interpret mode
 # everywhere (the CI validation path).
 LINSOLVES = ("xla", "ref", "pallas", "pallas-interpret")
 
@@ -111,7 +114,8 @@ def _newton_linsolve(linsolve: str, m_mat, rhs):
     matrices instead of B independent solves.  The solve runs in the
     dtype of ``m_mat`` (the mixed-precision path passes float32 here)."""
     if linsolve == "xla":
-        return jnp.linalg.solve(m_mat, rhs)
+        from jax.scipy.linalg import cho_factor, cho_solve
+        return cho_solve(cho_factor(m_mat, lower=True), rhs)
     if linsolve in ("ref", "pallas"):
         # ops.chol_solve owns the interpret-vs-compiled device dispatch
         from repro.kernels import ops as _kops
@@ -134,8 +138,8 @@ def _chol_factor32(linsolve: str, m32):
         return _kref.chol_factor_ref(m32)
     if linsolve in ("pallas", "pallas-interpret"):
         from repro.kernels import batched_chol as _bc
-        interpret = (linsolve == "pallas-interpret"
-                     or jax.default_backend() != "tpu")
+        from repro.kernels import ops as _kops
+        interpret = linsolve == "pallas-interpret" or not _kops._on_tpu()
         return _bc.chol_factor(m32, interpret=interpret)
     raise ValueError(f"unknown linsolve backend {linsolve!r}; "
                      f"expected one of {LINSOLVES}")
@@ -298,8 +302,12 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
         denom = n + has_ub.sum()
         return (x @ z + jnp.where(has_ub, s * w, 0.0).sum()) / denom
 
-    def make_body(newton_dtype: str):
+    def make_body(newton_dtype: str, graduated: bool = False):
+        """``graduated`` marks the float64 phase of the mixed-precision
+        path: those rows solve through the XLA Cholesky whatever the
+        backend, because the compiled Pallas kernel is float32-only."""
         f32 = newton_dtype == "float32"
+        row_linsolve = "xla" if graduated else linsolve
 
         def newton(x, y, z, w, s, r_p, r_d, r_u, rc_xz, rc_sw):
             # theta = z/x + w/s  (w/s only where bounded)
@@ -308,10 +316,15 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
             # rhs of normal equations
             rhat = (r_d - rc_xz / x
                     + jnp.where(has_ub, (rc_sw - w * r_u) / s, 0.0))
+            # no ridge: the standard form has full row rank (a slack per
+            # G row, disjoint task rows), and an absolute ridge swamps
+            # the tiny pivots of degenerate rows near a tight budget —
+            # with one, the Cholesky path stalls where LU got through
             m_mat = (a * theta_inv[None, :]) @ a.T
-            m_mat = m_mat + 1e-11 * jnp.eye(m, dtype=dtype)
+            if f32:
+                m_mat = m_mat + 1e-11 * jnp.eye(m, dtype=dtype)
             rhs = r_p + a @ (theta_inv * rhat)
-            dy, rel = _newton_solve(linsolve, newton_dtype, m_mat, rhs)
+            dy, rel = _newton_solve(row_linsolve, newton_dtype, m_mat, rhs)
             dx = theta_inv * (a.T @ dy - rhat)
             dz = (rc_xz - z * dx) / x
             ds = jnp.where(has_ub, r_u - dx, 0.0)
@@ -399,7 +412,8 @@ def _run_ipm(carry: _IPMCarry, make_body, iter_cap, newton_dtype: str
     """Iterate one IPM instance to ``iter_cap`` total iterations (a traced
     per-row cap under the chunked driver).  The mixed-precision path runs
     two phases: f32 Newton until the row graduates (small mu or a bad
-    refined residual), then f64 Newton to convergence."""
+    refined residual), then f64 Newton, through the XLA Cholesky, to
+    convergence."""
     if newton_dtype == "float32":
         body32 = make_body("float32")
 
@@ -407,7 +421,7 @@ def _run_ipm(carry: _IPMCarry, make_body, iter_cap, newton_dtype: str
             return (~cr.done) & (~cr.grad) & (cr.it < iter_cap)
 
         carry = jax.lax.while_loop(cond32, body32, carry)
-    body = make_body("float64")
+    body = make_body("float64", graduated=newton_dtype == "float32")
 
     def cond(cr: _IPMCarry):
         return (~cr.done) & (cr.it < iter_cap)
@@ -521,6 +535,14 @@ def _mesh_key_of(mesh, row_axes):
             tuple(int(d.id) for d in mesh.devices.flat))
 
 
+def _partitioner(mesh):
+    """GSPMD for sharded dispatches (see ``solver_partitioner``)."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    from repro.runtime.sharding import solver_partitioner
+    return solver_partitioner()
+
+
 def _row_pspec(row_axes):
     from jax.sharding import PartitionSpec as PS
     return PS(row_axes if len(row_axes) > 1 else row_axes[0])
@@ -582,11 +604,9 @@ def _stacked_solver_sharded(axes, max_iters: int, linsolve: str,
     stragglers stall only the shard that holds them, which is also why
     sharding speeds up even a lockstep (CPU/SIMD) backend.  LP rows are
     independent, so the program contains NO collectives
-    (``check_rep=False`` because the replication checker has no rule
+    (``check_vma=False`` because the replication checker has no rule
     for ``lax.while_loop``)."""
     from jax.sharding import PartitionSpec as PS
-
-    from repro.runtime.sharding import shard_map_compat
 
     def build():
         one = _stacked_one(max_iters, linsolve, newton_dtype)
@@ -594,9 +614,9 @@ def _stacked_solver_sharded(axes, max_iters: int, linsolve: str,
         rspec = _row_pspec(row_axes)
         in_specs = (PS(), rspec) + tuple(rspec if ax == 0 else PS()
                                          for ax in axes)
-        return jax.jit(shard_map_compat(vmapped, mesh=mesh,
-                                        in_specs=in_specs,
-                                        out_specs=rspec, check_rep=False))
+        return jax.jit(jax.shard_map(vmapped, mesh=mesh,
+                                     in_specs=in_specs,
+                                     out_specs=rspec, check_vma=False))
 
     return _registered_jit(("sharded", axes, max_iters, linsolve,
                             newton_dtype, _mesh_key_of(mesh, row_axes)),
@@ -873,12 +893,10 @@ def _chunk_merge_stepper(width: int, chunk_iters: int, max_iters: int,
         if mesh is None:
             return jax.jit(merge)
         from jax.sharding import PartitionSpec as PS
-
-        from repro.runtime.sharding import shard_map_compat
         rspec = _row_pspec(row_axes)
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             merge, mesh=mesh, in_specs=(PS(),) + (rspec,) * 9,
-            out_specs=(rspec,) * 5 + (PS(), PS()), check_rep=False))
+            out_specs=(rspec,) * 5 + (PS(), PS()), check_vma=False))
 
     return _registered_jit(("chunk-merge", width, chunk_iters, max_iters,
                             linsolve, newton_dtype,
@@ -906,14 +924,12 @@ def _chunk_finalize(n_orig: int, mesh=None, row_axes=None,
         if mesh is None:
             return jax.jit(fin)
         from jax.sharding import PartitionSpec as PS
-
-        from repro.runtime.sharding import shard_map_compat
         rspec = _row_pspec(row_axes)
         c_spec = rspec if c_batched else PS()
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             fin, mesh=mesh,
             in_specs=(rspec,) * 5 + (c_spec,) + (rspec,) * 3,
-            out_specs=rspec, check_rep=False))
+            out_specs=rspec, check_vma=False))
 
     return _registered_jit(("chunk-finalize", n_orig,
                             _mesh_key_of(mesh, row_axes), c_batched), build)
@@ -1287,7 +1303,8 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                                mesh_shape=mesh_shape)
         with obs.span("lp.solve_stacked", width=batch, compact=True,
                       linsolve=linsolve, newton_dtype=newton_dtype,
-                      compact_mode=compact_mode, n_shards=n_shards):
+                      compact_mode=compact_mode, n_shards=n_shards), \
+                _partitioner(mesh):
             sol, it32, bad, compact_rows = _solve_stacked_compact(
                 arrs, axes, batch, tol, active, max_iters=max_iters,
                 chunk_iters=chunk_iters, linsolve=linsolve,
@@ -1311,7 +1328,7 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     # the measured time is real solve time, not lazy-dispatch time
     with obs.span("lp.solve_stacked", width=batch, compact=False,
                   linsolve=linsolve, newton_dtype=newton_dtype,
-                  n_shards=n_shards):
+                  n_shards=n_shards), _partitioner(mesh):
         solver = (_stacked_solver(axes, max_iters, linsolve, newton_dtype)
                   if mesh is None else
                   _stacked_solver_sharded(axes, max_iters, linsolve,

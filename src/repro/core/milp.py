@@ -19,7 +19,7 @@ one under the monolithic driver, one per power-of-two ladder width
 under the chunked ``compact=True`` driver
 (``lp.stacked_compile_count`` tracks it).  Nodes whose IPM solve does
 not converge cleanly are re-solved with HiGHS (robust infeasibility
-certificates).
+certificates); the ``milp.host_resolves`` counter counts them.
 """
 from __future__ import annotations
 
@@ -64,12 +64,16 @@ class MILPResult:
 
 def _solve_node(node, prefer_jax: bool = True, linsolve: str = "xla",
                 newton_dtype: str = "float64"):
-    """Returns (x, obj, status) with status in {ok, infeasible}."""
+    """Returns (x, obj, status) with status in {ok, infeasible}.  Every
+    node LP that ends on the HiGHS host path counts in the
+    ``milp.host_resolves`` counter, so a device solver that stops
+    converging shows up instead of hiding behind correct frontiers."""
     if prefer_jax:
         sol = lpmod.solve_node_lp(node, linsolve=linsolve,
                                   newton_dtype=newton_dtype)
         if bool(sol.converged):
             return np.asarray(sol.x), float(sol.obj), "ok"
+    obs.inc("milp.host_resolves")
     res = lpmod.scipy_reference_lp(node.c, node.a_eq, node.b_eq, node.g,
                                    node.h, node.lb, node.ub)
     if res.status == 2:

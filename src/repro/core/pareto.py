@@ -220,6 +220,7 @@ def milp_tradeoff_batched(problem: AllocationProblem, n_points: int = 8,
     caps = np.linspace(c_l, max(c_u, c_l), n_points)
     _, lbs, sols = relaxation_frontier(problem, caps, return_solutions=True,
                                        **_stacked_solve_kw(kw))
+    lbs = _trusted_bounds(lbs, sols.converged)
     xs = np.asarray(sols.x)
     relax_allocs = [problem.split_node_x(xs[k])[0] for k in range(len(caps))]
     points = _warm_sweep(problem, caps, lbs, relax_allocs, top,
@@ -332,7 +333,9 @@ def _batched_scenario_relaxation(probs, caps_list, dead_masks,
                                  mesh=None, row_spec=None):
     """One stacked IPM call across every (scenario, budget) pair.
 
-    Returns (lbs (S, K), relax_allocs (S, K) list-of-lists).  Dead
+    Returns (lbs (S, K), relax_allocs (S, K) list-of-lists, bounds
+    (S, K)); ``bounds`` are the lbs usable as B&B lower bounds
+    (:func:`_trusted_bounds`).  Dead
     platforms are pinned to zero allocation via the node's variable
     bounds, not just the latency penalty.  ``mesh`` shards the
     (scenario x budget) row axis over a device mesh — this megabatch is
@@ -349,10 +352,20 @@ def _batched_scenario_relaxation(probs, caps_list, dead_masks,
                                         mesh=mesh, row_spec=row_spec)
     s, k = len(probs), len(caps_list[0])
     lbs = np.asarray(sols.obj).reshape(s, k)
+    bounds = _trusted_bounds(lbs, np.asarray(sols.converged).reshape(s, k))
     xs = np.asarray(sols.x).reshape(s, k, -1)
     allocs = [[probs[i].split_node_x(xs[i, j])[0] for j in range(k)]
               for i in range(s)]
-    return lbs, allocs
+    return lbs, allocs, bounds
+
+
+def _trusted_bounds(lbs, converged) -> np.ndarray:
+    """Relaxation objectives a B&B may take as lower bounds: the
+    objective of a row the IPM did not converge bounds nothing (it can
+    sit above the optimum and would close a tree at a wrong incumbent),
+    so such rows become ``-inf`` — no bound."""
+    return np.where(np.asarray(converged, bool), np.asarray(lbs, float),
+                    -np.inf)
 
 
 # sweep kwargs that also steer the batched relaxation solves: extracted
@@ -390,7 +403,7 @@ def scenario_relaxation_frontiers(problem: AllocationProblem, scenarios,
     probs = scen.problems(problem)
     caps_list = [np.linspace(*_cheap_cost_bounds(p, s.dead), n_points)
                  for p, s in zip(probs, scen)]
-    lbs, _ = _batched_scenario_relaxation(
+    lbs, _, _ = _batched_scenario_relaxation(
         probs, caps_list, [s.dead for s in scen], linsolve=linsolve,
         compact=compact, chunk_iters=chunk_iters,
         newton_dtype=newton_dtype, mesh=mesh, row_spec=row_spec)
@@ -411,7 +424,7 @@ def scenario_frontiers(problem: AllocationProblem, scenarios,
     bounds = [cost_bounds_batched(p, **_bnb_kw(kw)) for p in probs]
     caps_list = [np.linspace(c_l, max(c_u, c_l), n_points)
                  for c_l, c_u, _ in bounds]
-    lbs, relax_allocs = _batched_scenario_relaxation(
+    _, relax_allocs, lbs = _batched_scenario_relaxation(
         probs, caps_list, [s.dead for s in scen], **_stacked_solve_kw(kw))
     out = {}
     for i, s in enumerate(scen):

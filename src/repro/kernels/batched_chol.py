@@ -1,26 +1,34 @@
-"""Pallas kernel: blocked batched Cholesky solve for the stacked IPM.
+"""Pallas kernel: batched Cholesky solve for the stacked IPM.
 
 The interior-point LP engine (:mod:`repro.core.lp`) reduces every Newton
 step to one symmetric positive-definite normal-equation solve per batch
-row: ``M dy = r`` with ``M = A Theta^{-1} A^T + ridge`` of shape
-``(m, m)``, ``m`` = #constraint rows (tens).  A vmapped
-``jnp.linalg.solve`` dispatches a batched LU through lapack on CPU; on
-TPU the natural shape is one kernel launch over the stacked ``(B, m, m)``
+row: ``M dy = r`` with ``M = A Theta^{-1} A^T`` of shape
+``(m, m)``, ``m`` = #constraint rows (tens to a few hundred).  On TPU the
+natural shape is one kernel launch over the stacked ``(B, m, m)``
 matrices with each grid cell factoring its matrix entirely in VMEM.
 
-Design (paper thesis: move the whole solver inner loop onto the
-accelerator): the matrix is padded to a multiple of the block size, a
-left-looking *blocked* Cholesky runs over column blocks — an unrolled
-``nb x nb`` diagonal factorisation, a triangular panel solve, and an
-``(m - k) x nb`` trailing matmul that maps to the MXU — followed by
-blocked forward/backward substitution for the right-hand side.  Shapes
-are static, so the Python block loop unrolls at trace time; there is no
-HBM traffic inside the factorisation.
+Design: a right-looking Cholesky over a ``fori_loop`` of columns.  Step
+``j`` reads column and row ``j`` of the trailing matrix with masked
+reductions over an iota, forms ``l_j = a[:, j] / sqrt(a[j, j])`` and
+subtracts the rank-1 outer product ``l_j l_j^T`` from the whole matrix
+(``l_j`` is zero above row ``j``, so only the trailing block moves).
+Every read and write is a ``jnp.where`` select against an iota — no
+scatter and no dynamic slice — which is what Mosaic lowers; the loop
+index is a traced scalar, so the compiled kernel does not grow with
+``m``.  Forward substitution rides along with the factorisation (the
+right-hand side is updated with the same ``l_j``), and backward
+substitution is a second column loop over the stored factor.  Vectors
+are kept as ``(1, m)`` rows so the lane dimension carries them.
+
+The compiled kernel runs in float32 only (Mosaic has no float64 vector
+unit); :func:`chol_solve` / :func:`chol_factor` raise on other dtypes
+when not interpreting.  The IPM's mixed-precision path
+(``newton_dtype="float32"``) is the one that feeds it on TPU.  Interpret
+mode (the CPU test path) is dtype-generic.
 
 ``jax.vmap`` of the single-matrix call batches the grid (this is how the
 vmapped IPM turns B per-row solves into ONE batched-Cholesky call); the
 public :func:`chol_solve` also accepts stacked inputs directly.
-Validated in interpret mode on CPU (the tier-1 path); compiled on TPU.
 """
 from __future__ import annotations
 
@@ -28,139 +36,135 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+# the system is padded (identity tail) to a multiple of this — the
+# float32 sublane tile; the solution does not depend on it
 DEFAULT_BLOCK = 8
 
 
 # ---------------------------------------------------------------------------
-# In-kernel building blocks (static shapes, unrolled at trace time)
+# In-kernel building blocks
 # ---------------------------------------------------------------------------
 
-def _chol_unblocked(a):
-    """Cholesky of a small (nb, nb) SPD block, column by column."""
-    nb = a.shape[0]
-    l = jnp.zeros_like(a)
-    for j in range(nb):
-        ajj = a[j, j] - (l[j, :j] * l[j, :j]).sum() if j else a[j, j]
-        d = jnp.sqrt(ajj)
-        l = l.at[j, j].set(d)
-        if j + 1 < nb:
-            colv = a[j + 1:, j] - l[j + 1:, :j] @ l[j, :j] if j \
-                else a[j + 1:, j]
-            l = l.at[j + 1:, j].set(colv / d)
-    return l
-
-
-def _trsm_right_lt(b, l):
-    """Solve ``X L^T = B`` for X; L lower-triangular (nb, nb), B (r, nb)."""
-    nb = l.shape[0]
-    x = jnp.zeros_like(b)
-    for j in range(nb):
-        bj = b[:, j] - x[:, :j] @ l[j, :j] if j else b[:, j]
-        x = x.at[:, j].set(bj / l[j, j])
-    return x
-
-
-def _fwd_unblocked(l, b):
-    """Solve ``L y = b`` for a small (nb, nb) lower-triangular block."""
-    nb = l.shape[0]
-    y = jnp.zeros_like(b)
-    for j in range(nb):
-        bj = b[j] - l[j, :j] @ y[:j] if j else b[j]
-        y = y.at[j].set(bj / l[j, j])
-    return y
-
-
-def _bwd_unblocked(l, b):
-    """Solve ``L^T x = b`` for a small (nb, nb) lower-triangular block."""
-    nb = l.shape[0]
-    x = jnp.zeros_like(b)
-    for j in reversed(range(nb)):
-        bj = b[j] - l[j + 1:, j] @ x[j + 1:] if j + 1 < nb else b[j]
-        x = x.at[j].set(bj / l[j, j])
-    return x
-
-
-def _chol_factor_blocked(a, nb):
-    """Left-looking blocked Cholesky; returns L with zeroed upper part."""
+def _factor(a, b):
+    """Right-looking Cholesky of ``a`` (mp, mp) with forward substitution
+    of the row vector ``b`` (1, mp) fused in.  Returns ``(l, d, y)``: the
+    lower factor, its diagonal as a (1, mp) row and ``y = L^{-1} b^T``
+    as a (1, mp) row."""
     mp = a.shape[0]
-    if nb >= mp:        # single block: whole-array .at updates trip the
-        return _chol_unblocked(a)   # pallas const-capture check
-    l = jnp.zeros_like(a)
-    for k0 in range(0, mp, nb):
-        k1 = k0 + nb
-        akk = a[k0:k1, k0:k1] - l[k0:k1, :k0] @ l[k0:k1, :k0].T if k0 \
-            else a[k0:k1, k0:k1]
-        lkk = _chol_unblocked(akk)
-        l = l.at[k0:k1, k0:k1].set(lkk)
-        if k1 < mp:
-            a21 = a[k1:, k0:k1] - l[k1:, :k0] @ l[k0:k1, :k0].T if k0 \
-                else a[k1:, k0:k1]
-            l = l.at[k1:, k0:k1].set(_trsm_right_lt(a21, lkk))
-    return l
+    dt = a.dtype
+    ri = jax.lax.broadcasted_iota(jnp.int32, (mp, mp), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (mp, mp), 1)
+    rc = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
+    cr = jax.lax.broadcasted_iota(jnp.int32, (1, mp), 1)
+    zero = jnp.zeros((), dt)
+
+    def step(j, carry):
+        a, l, d, b, y = carry
+        col = jnp.sum(jnp.where(ci == j, a, zero), axis=1, keepdims=True)
+        row = jnp.sum(jnp.where(ri == j, a, zero), axis=0, keepdims=True)
+        djj = jnp.sqrt(jnp.sum(jnp.where(cr == j, row, zero), axis=1,
+                               keepdims=True))                   # (1, 1)
+        lcol = jnp.where(rc >= j, col / djj, zero)               # (mp, 1)
+        lrow = jnp.where(cr >= j, row / djj, zero)               # (1, mp)
+        a = a - lcol * lrow
+        l = l + jnp.where(ci == j, lcol, zero)
+        d = d + jnp.where(cr == j, djj, zero)
+        yj = jnp.sum(jnp.where(cr == j, b, zero), axis=1,
+                     keepdims=True) / djj                        # (1, 1)
+        b = b - yj * lrow
+        y = y + jnp.where(cr == j, yj, zero)
+        return a, l, d, b, y
+
+    zrow = jnp.zeros((1, mp), dt)
+    # int32 loop bounds: under x64 a Python-int loop index is int64,
+    # which Mosaic does not lower
+    _, l, d, _, y = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(mp), step,
+        (a, jnp.zeros_like(a), zrow, b, zrow))
+    return l, d, y
 
 
-def _solve_lower_blocked(l, b, nb):
-    """Blocked forward substitution ``L y = b``."""
+def _backward(l, d, y):
+    """Solve ``L^T x = y`` (rows (1, mp)) by a descending column loop."""
     mp = l.shape[0]
-    if nb >= mp:
-        return _fwd_unblocked(l, b)
-    y = jnp.zeros_like(b)
-    for k0 in range(0, mp, nb):
-        k1 = k0 + nb
-        rhs = b[k0:k1] - l[k0:k1, :k0] @ y[:k0] if k0 else b[k0:k1]
-        y = y.at[k0:k1].set(_fwd_unblocked(l[k0:k1, k0:k1], rhs))
-    return y
+    dt = l.dtype
+    ri = jax.lax.broadcasted_iota(jnp.int32, (mp, mp), 0)
+    cr = jax.lax.broadcasted_iota(jnp.int32, (1, mp), 1)
+    zero = jnp.zeros((), dt)
 
+    def step(k, carry):
+        r, x = carry
+        j = mp - 1 - k
+        lrow = jnp.sum(jnp.where(ri == j, l, zero), axis=0, keepdims=True)
+        xj = (jnp.sum(jnp.where(cr == j, r, zero), axis=1, keepdims=True)
+              / jnp.sum(jnp.where(cr == j, d, zero), axis=1, keepdims=True))
+        r = r - xj * jnp.where(cr < j, lrow, zero)
+        x = x + jnp.where(cr == j, xj, zero)
+        return r, x
 
-def _solve_upper_blocked(l, y, nb):
-    """Blocked backward substitution ``L^T x = y``."""
-    mp = l.shape[0]
-    if nb >= mp:
-        return _bwd_unblocked(l, y)
-    x = jnp.zeros_like(y)
-    for k0 in reversed(range(0, mp, nb)):
-        k1 = k0 + nb
-        rhs = y[k0:k1] - l[k1:, k0:k1].T @ x[k1:] if k1 < mp else y[k0:k1]
-        x = x.at[k0:k1].set(_bwd_unblocked(l[k0:k1, k0:k1], rhs))
+    _, x = jax.lax.fori_loop(jnp.int32(0), jnp.int32(mp), step,
+                             (y, jnp.zeros_like(y)))
     return x
 
 
-def _chol_solve_kernel(a_ref, b_ref, x_ref, *, nb: int):
+def _chol_solve_kernel(a_ref, b_ref, x_ref):
+    l, d, y = _factor(a_ref[...], b_ref[...])
+    x_ref[...] = _backward(l, d, y)
+
+
+def _chol_factor_kernel(a_ref, l_ref):
     a = a_ref[...]
-    b = b_ref[...][:, 0]
-    l = _chol_factor_blocked(a, nb)
-    y = _solve_lower_blocked(l, b, nb)
-    x = _solve_upper_blocked(l, y, nb)
-    x_ref[...] = x[:, None]
-
-
-def _chol_factor_kernel(a_ref, l_ref, *, nb: int):
-    l_ref[...] = _chol_factor_blocked(a_ref[...], nb)
+    l, _, _ = _factor(a, jnp.zeros((1, a.shape[0]), a.dtype))
+    l_ref[...] = l
 
 
 # ---------------------------------------------------------------------------
 # Host-side wrappers
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("nb", "interpret"))
-def _chol_solve_padded(a, b, *, nb: int, interpret: bool):
+def _whole(shape):
+    """Whole-array block with an int32 index map: the default map returns
+    Python-int zeros, which trace to int64 under x64 and which Mosaic
+    refuses once ``vmap`` adds the batch grid axis."""
+    zeros = (np.int32(0),) * len(shape)
+    return pl.BlockSpec(shape, lambda: zeros)
+
+
+def _check_compiled_dtype(dtype, interpret: bool) -> None:
+    if not interpret and jnp.dtype(dtype) != jnp.float32:
+        raise ValueError(
+            f"the compiled Pallas Cholesky kernel runs in float32 only, got "
+            f"{jnp.dtype(dtype).name}; on TPU use linsolve='pallas' with "
+            f"newton_dtype='float32' (rows that graduate to float64 then "
+            f"solve through the XLA Cholesky), or linsolve='xla'")
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _chol_solve_padded(a, b, *, interpret: bool):
     mp = a.shape[0]
     return pl.pallas_call(
-        functools.partial(_chol_solve_kernel, nb=nb),
-        out_shape=jax.ShapeDtypeStruct((mp, 1), a.dtype),
+        _chol_solve_kernel,
+        out_shape=jax.ShapeDtypeStruct((1, mp), a.dtype),
+        in_specs=[_whole((mp, mp)), _whole((1, mp))],
+        out_specs=_whole((1, mp)),
         interpret=interpret,
+        name="batched_chol_solve",
     )(a, b)
 
 
-@functools.partial(jax.jit, static_argnames=("nb", "interpret"))
-def _chol_factor_padded(a, *, nb: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _chol_factor_padded(a, *, interpret: bool):
     mp = a.shape[0]
     return pl.pallas_call(
-        functools.partial(_chol_factor_kernel, nb=nb),
+        _chol_factor_kernel,
         out_shape=jax.ShapeDtypeStruct((mp, mp), a.dtype),
+        in_specs=[_whole((mp, mp))],
+        out_specs=_whole((mp, mp)),
         interpret=interpret,
+        name="batched_chol_factor",
     )(a)
 
 
@@ -193,18 +197,19 @@ def chol_solve_one(a, b, *, block: int = DEFAULT_BLOCK,
     if dtype is not None:
         a = a.astype(dtype)
         b = b.astype(dtype)
+    _check_compiled_dtype(a.dtype, interpret)
     mp = _padded_size(a.shape[-1], block)
     ap, bp = _pad_spd(a, b, mp)
-    x = _chol_solve_padded(ap, bp[:, None], nb=block, interpret=interpret)
-    return x[:, 0][:a.shape[-1]]
+    x = _chol_solve_padded(ap, bp[None, :], interpret=interpret)
+    return x[0, :a.shape[-1]]
 
 
 def chol_solve(mats, rhs, *, block: int = DEFAULT_BLOCK,
                interpret: bool = True, dtype=None):
     """Batched SPD solve: ``mats`` (B, m, m) or (m, m), ``rhs`` (B, m) or
     (m,).  The batch runs as ONE Pallas launch (vmap adds the grid axis).
-    ``dtype`` (optional) casts the inputs before the solve — the kernel
-    itself is dtype-generic and accepts float32 stacks directly."""
+    ``dtype`` (optional) casts the inputs before the solve; interpret
+    mode is dtype-generic, the compiled kernel takes float32 only."""
     mats = jnp.asarray(mats)
     rhs = jnp.asarray(rhs)
     if mats.ndim == 2:
@@ -217,12 +222,14 @@ def chol_solve(mats, rhs, *, block: int = DEFAULT_BLOCK,
 
 def chol_factor(mats, *, block: int = DEFAULT_BLOCK, interpret: bool = True,
                 dtype=None):
-    """Batched blocked Cholesky factor L (lower; L @ L.T == mats), for
-    kernel-vs-oracle parity tests.  ``dtype`` casts the input stack
-    first (float32 runs the whole factorisation in float32)."""
+    """Batched Cholesky factor L (lower; L @ L.T == mats) — the
+    factorisation the mixed-precision Newton path reuses for its
+    refinement step.  ``dtype`` casts the input stack first (float32
+    runs the whole factorisation in float32)."""
     mats = jnp.asarray(mats)
     if dtype is not None:
         mats = mats.astype(dtype)
+    _check_compiled_dtype(mats.dtype, interpret)
     single = mats.ndim == 2
     if single:
         mats = mats[None]
@@ -231,7 +238,7 @@ def chol_factor(mats, *, block: int = DEFAULT_BLOCK, interpret: bool = True,
 
     def one(a):
         ap, _ = _pad_spd(a, jnp.zeros((m,), mats.dtype), mp)
-        return _chol_factor_padded(ap, nb=block, interpret=interpret)
+        return _chol_factor_padded(ap, interpret=interpret)
 
     ls = jax.vmap(one)(mats)[:, :m, :m]
     return ls[0] if single else ls

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -78,7 +79,9 @@ def uniform01(bits: jnp.ndarray) -> jnp.ndarray:
     Using the top 24 bits keeps the conversion exact in float32; the +1ulp
     shift avoids log(0) in Box-Muller.
     """
-    u = (bits >> 8).astype(jnp.float32) * np.float32(1.0 / (1 << 24))
+    # via int32 (exact below 2^24): Mosaic lowers no uint32 -> float cast
+    top = jax.lax.bitcast_convert_type(bits >> 8, jnp.int32)
+    u = top.astype(jnp.float32) * np.float32(1.0 / (1 << 24))
     return u + np.float32(1.0 / (1 << 25))
 
 

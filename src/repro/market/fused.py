@@ -415,12 +415,10 @@ def run_episodes_vmapped(catalog, n, episodes: Sequence[MarketEpisode], *,
         vf = jax.vmap(fn, in_axes=(None,) * 5 + (0,) * 10)
         if mesh is not None:
             from jax.sharding import PartitionSpec as PS
-
-            from repro.runtime.sharding import shard_map_compat
             rspec = lpmod._row_pspec(row_axes)
-            vf = shard_map_compat(vf, mesh=mesh,
-                                  in_specs=(PS(),) * 5 + (rspec,) * 10,
-                                  out_specs=rspec, check_rep=False)
+            vf = jax.shard_map(vf, mesh=mesh,
+                               in_specs=(PS(),) * 5 + (rspec,) * 10,
+                               out_specs=rspec, check_vma=False)
         vfn = jax.jit(vf)
         _FUSED_REPLAYS[key] = vfn
     _record_fused_compile(policy_kind, n_weights, tensors[0].n_slots,

@@ -209,7 +209,7 @@ class WarmMILPPolicy(Policy):
         c_l, c_u = pareto._cheap_cost_bounds(p, dead)
         caps = np.linspace(c_l, max(c_u, c_l) * self.cap_headroom,
                            self.n_caps)
-        lbs, relax_allocs = pareto._batched_scenario_relaxation(
+        _, relax_allocs, lbs = pareto._batched_scenario_relaxation(
             [p], [caps], [dead], **self._solver_kw())
         prev = None
         if self._alloc is not None:

@@ -29,18 +29,6 @@ import numpy as np
 
 from repro.models.params import P
 
-# jax.shard_map graduated from jax.experimental in jax 0.5 (and renamed
-# its replication-check kwarg check_rep -> check_vma); support both
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                        # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_rep=check_vma)
-
 _EP_AXIS = "model"
 
 
@@ -195,7 +183,7 @@ def moe_apply(params, x, cfg, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
         routed = {k: params[k] for k in
                   ("router", "w_gate", "w_up", "w_down")}
-        out, aux = _shard_map(body2d, mesh=ctx.mesh, in_specs=in_specs,
+        out, aux = jax.shard_map(body2d, mesh=ctx.mesh, in_specs=in_specs,
                                  out_specs=out_specs,
                                  check_vma=False)(routed, x)
     else:
@@ -225,7 +213,7 @@ def moe_apply(params, x, cfg, ctx) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
         routed = {k: params[k] for k in
                   ("router", "w_gate", "w_up", "w_down")}
-        out, aux = _shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+        out, aux = jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
                                  out_specs=out_specs,
                                  check_vma=False)(routed, x)
 

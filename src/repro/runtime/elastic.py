@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core import heuristics, milp, pareto
+from repro.core import lp as lpmod
 from repro.core.problem import AllocationProblem
 
 
@@ -142,14 +143,10 @@ class ElasticController:
                 if plan is not None:
                     cands.append(self._project_live(plan, live))
             warm = pareto.warm_candidate(sub, self.cost_cap, cands)
-            lb0 = None
-            try:
-                from repro.core import lp as lpmod
-                sol = lpmod.solve_node_lp(sub.node_lp(self.cost_cap))
-                if bool(sol.converged):
-                    lb0 = float(sol.obj)
-            except Exception:
-                lb0 = None
+            # an unsatisfiable budget leaves the relaxation unconverged
+            # (the IPM raises nothing): the B&B then starts unbounded
+            sol = lpmod.solve_node_lp(sub.node_lp(self.cost_cap))
+            lb0 = float(sol.obj) if bool(sol.converged) else None
             res = milp.solve(sub, cost_cap=self.cost_cap, backend="bnb",
                              warm_alloc=warm, lower_bound0=lb0, **kw)
         else:

@@ -18,23 +18,17 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as PS
 
-# jax.shard_map graduated from jax.experimental in jax 0.5 (and renamed
-# its replication-check kwarg check_rep -> check_vma); support both.
-if hasattr(jax, "shard_map"):
-    _SHARD_MAP, _CHECK_KW = jax.shard_map, "check_vma"
-else:                                        # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-    _SHARD_MAP, _CHECK_KW = _experimental_sm, "check_rep"
+def solver_partitioner():
+    """Partitioner context for dispatching a sharded stacked-IPM program.
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_rep=True):
-    """Version-stable :func:`jax.shard_map` wrapper (the ``check_rep``
-    kwarg was renamed ``check_vma`` when shard_map left experimental).
-    The stacked-IPM wrappers pass ``check_rep=False``: the per-shard
-    program contains ``lax.while_loop``s, which the replication checker
-    has no rule for."""
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_rep})
+    Sharded solves partition with GSPMD rather than Shardy: on TPU the
+    Shardy-partitioned program is refused at XLA's float64 Cholesky ("A
+    tuple parameter that is being flattened shouldn't have frontend
+    attributes", jax 0.9 / libtpu 0.0.34), while GSPMD compiles it.  The
+    choice is part of jit's cache key, so every dispatch of a sharded
+    program runs inside this context."""
+    from jax._src import config as jax_config   # no public handle
+    return jax_config.use_shardy_partitioner(False)
 
 
 def logical_rules(mesh, *, shard_seq: bool = False, mode: str = "train"

@@ -89,7 +89,8 @@ model = build_model(cfg)
 params = init_params(model.param_defs(), jax.random.PRNGKey(0))
 pipe = SyntheticPipeline(vocab=cfg.vocab, seq_len=64, global_batch=4)
 batch = pipe.batch(0)
-mesh = jax.make_mesh((1, 4), ('data', 'model'))
+from repro.launch.mesh import make_smoke_mesh
+mesh = make_smoke_mesh(1, 4)
 with mesh:
     l0 = jax.jit(lambda p, b: make_loss_fn(model, ModelContext(
         mesh=mesh, batch_axes=('data',)), TrainConfig())(p, b)[0])(params, batch)

@@ -723,20 +723,23 @@ def newton_ledger():
 
 
 def _record_newton_rows(iters, active, converged=None, it32=None, bad=None,
-                        compact_rows=None) -> None:
+                        compact_rows=None) -> dict:
+    """Record one stacked call in the ledger; returns the call's
+    ``active_rows``, ``paid_rows`` (the ledger's ``compact_rows``: what
+    the executing driver paid) and ``iters_max``."""
     iters = np.asarray(iters)
     active = np.asarray(active)
     act = iters[active]
     if act.size == 0:
-        return
+        return dict(active_rows=0, paid_rows=0, iters_max=0)
     lockstep = int(iters.shape[0] * act.max())
     n_act = int(act.sum())
+    paid = lockstep if compact_rows is None else int(compact_rows)
     counters = {
         "lp.newton.calls": 1,
         "lp.newton.lockstep_rows": lockstep,
         "lp.newton.active_rows": n_act,
-        "lp.newton.compact_rows": (lockstep if compact_rows is None
-                                   else int(compact_rows)),
+        "lp.newton.compact_rows": paid,
     }
     if it32 is not None:
         f32 = int(np.asarray(it32)[active].sum())
@@ -754,6 +757,7 @@ def _record_newton_rows(iters, active, converged=None, it32=None, bad=None,
     # (server scheduler thread + main thread) cannot interleave halves
     obs.update(counters=counters,
                observations={"lp.newton.iters": act.tolist()})
+    return dict(active_rows=n_act, paid_rows=paid, iters_max=int(act.max()))
 
 
 # ---------------------------------------------------------------------------
@@ -1243,7 +1247,9 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     chunk_iters = _CHUNK_ITERS if chunk_iters is None else int(chunk_iters)
     if chunk_iters < 1:
         raise ValueError(f"chunk_iters must be >= 1, got {chunk_iters}")
-    arrs = tuple(jnp.asarray(v, dt) for v in (c, a_eq, b_eq, g, h, lb, ub))
+    with obs.span("lp.put"):
+        arrs = tuple(jnp.asarray(v, dt)
+                     for v in (c, a_eq, b_eq, g, h, lb, ub))
     axes = tuple(0 if a.ndim == base + 1 else None
                  for a, base in zip(arrs, _BASE_NDIM))
     for a, base, ax in zip(arrs, _BASE_NDIM, axes):
@@ -1303,16 +1309,20 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                                mesh_shape=mesh_shape)
         with obs.span("lp.solve_stacked", width=batch, compact=True,
                       linsolve=linsolve, newton_dtype=newton_dtype,
-                      compact_mode=compact_mode, n_shards=n_shards), \
+                      compact_mode=compact_mode, n_shards=n_shards) as sp, \
                 _partitioner(mesh):
-            sol, it32, bad, compact_rows = _solve_stacked_compact(
-                arrs, axes, batch, tol, active, max_iters=max_iters,
-                chunk_iters=chunk_iters, linsolve=linsolve,
-                newton_dtype=newton_dtype, compact_mode=compact_mode,
-                mesh=mesh, row_axes=row_axes)
-            _record_newton_rows(sol.iters, active, converged=sol.converged,
-                                it32=it32, bad=bad,
-                                compact_rows=compact_rows)
+            with obs.span("lp.dispatch"):
+                sol, it32, bad, compact_rows = _solve_stacked_compact(
+                    arrs, axes, batch, tol, active, max_iters=max_iters,
+                    chunk_iters=chunk_iters, linsolve=linsolve,
+                    newton_dtype=newton_dtype, compact_mode=compact_mode,
+                    mesh=mesh, row_axes=row_axes)
+            with obs.span("lp.device_wait"):
+                jax.block_until_ready((sol, it32, bad))
+            with obs.span("lp.ledger"):
+                sp.set(**_record_newton_rows(
+                    sol.iters, active, converged=sol.converged, it32=it32,
+                    bad=bad, compact_rows=compact_rows))
         return LPSolution(*(f[:n_req] for f in sol)) if pad else sol
     sig = (axes, max_iters, linsolve, newton_dtype,
            tuple(a.shape for a in arrs), mesh_key)
@@ -1323,19 +1333,24 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                            newton_dtype=newton_dtype, compact=False,
                            chunk_iters=None, row_shape=row_shape,
                            mesh_shape=mesh_shape)
-    # the span covers the (possibly compiling) dispatch AND the ledger
-    # record, whose np.asarray blocks on the async device result — so
-    # the measured time is real solve time, not lazy-dispatch time
+    # the span covers the (possibly compiling) dispatch AND the wait for
+    # the async device result — so the measured time is real solve time,
+    # not lazy-dispatch time
     with obs.span("lp.solve_stacked", width=batch, compact=False,
                   linsolve=linsolve, newton_dtype=newton_dtype,
-                  n_shards=n_shards), _partitioner(mesh):
+                  n_shards=n_shards) as sp, _partitioner(mesh):
         solver = (_stacked_solver(axes, max_iters, linsolve, newton_dtype)
                   if mesh is None else
                   _stacked_solver_sharded(axes, max_iters, linsolve,
                                           newton_dtype, mesh, row_axes))
-        sol, it32, bad = solver(jnp.asarray(tol, dt), active, *arrs)
-        _record_newton_rows(sol.iters, active, converged=sol.converged,
-                            it32=it32, bad=bad)
+        with obs.span("lp.dispatch"):
+            sol, it32, bad = solver(jnp.asarray(tol, dt), active, *arrs)
+        with obs.span("lp.device_wait"):
+            jax.block_until_ready((sol, it32, bad))
+        with obs.span("lp.ledger"):
+            sp.set(**_record_newton_rows(sol.iters, active,
+                                         converged=sol.converged,
+                                         it32=it32, bad=bad))
     return LPSolution(*(f[:n_req] for f in sol)) if pad else sol
 
 
@@ -1351,8 +1366,9 @@ def solve_node_lps_stacked(nodes, *, max_iters: int = _MAX_ITERS,
     nodes = list(nodes)
     if not nodes:
         raise ValueError("empty node stack")
-    stacked = [np.stack([np.asarray(getattr(n, f)) for n in nodes])
-               for f in ("c", "a_eq", "b_eq", "g", "h", "lb", "ub")]
+    with obs.span("lp.put"):
+        stacked = [np.stack([np.asarray(getattr(n, f)) for n in nodes])
+                   for f in ("c", "a_eq", "b_eq", "g", "h", "lb", "ub")]
     return solve_lp_stacked(*stacked, max_iters=max_iters, tol=tol,
                             linsolve=linsolve, row_active=row_active,
                             compact=compact, chunk_iters=chunk_iters,

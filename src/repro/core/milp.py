@@ -74,8 +74,9 @@ def _solve_node(node, prefer_jax: bool = True, linsolve: str = "xla",
         if bool(sol.converged):
             return np.asarray(sol.x), float(sol.obj), "ok"
     obs.inc("milp.host_resolves")
-    res = lpmod.scipy_reference_lp(node.c, node.a_eq, node.b_eq, node.g,
-                                   node.h, node.lb, node.ub)
+    with obs.span("milp.host_resolve"):
+        res = lpmod.scipy_reference_lp(node.c, node.a_eq, node.b_eq,
+                                       node.g, node.h, node.lb, node.ub)
     if res.status == 2:
         return None, np.inf, "infeasible"
     if not res.success:
@@ -481,56 +482,61 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
         rounds += 1
         with obs.span("milp.round", round=rounds, popped=len(popped),
                       width=batch_width) as round_span:
-            lps = [problem.node_lp(tr["cap"], nd["b0"], nd["b1"],
-                                   nd["d_lb"], nd["d_ub"])
-                   for tr, nd in popped]
-            # fixed batch width: pad with row 0 so jit compiles once per
-            # sweep.  lp_tol ~ 1e-7 (vs the 1e-9 reference default): node
-            # solves only need bounding accuracy well inside gap_tol, and
-            # the whole batch iterates until its SLOWEST member converges.
-            batch = lps + [lps[0]] * (batch_width - len(lps))
-            active = None
-            if early_exit:
-                active = np.arange(batch_width) < len(lps)
+            with obs.span("milp.assemble"):
+                lps = [problem.node_lp(tr["cap"], nd["b0"], nd["b1"],
+                                       nd["d_lb"], nd["d_ub"])
+                       for tr, nd in popped]
+                # fixed batch width: pad with row 0 so jit compiles once
+                # per sweep.  lp_tol ~ 1e-7 (vs the 1e-9 reference
+                # default): node solves only need bounding accuracy well
+                # inside gap_tol, and the whole batch iterates until its
+                # SLOWEST member converges.
+                batch = lps + [lps[0]] * (batch_width - len(lps))
+                active = None
+                if early_exit:
+                    active = np.arange(batch_width) < len(lps)
             sols = lpmod.solve_node_lps_stacked(batch, tol=lp_tol,
                                                 linsolve=linsolve,
                                                 row_active=active,
                                                 compact=compact,
                                                 chunk_iters=chunk_iters,
                                                 newton_dtype=newton_dtype)
-            xs = np.asarray(sols.x)
-            objs = np.asarray(sols.obj)
-            conv = np.asarray(sols.converged)
+            with obs.span("milp.fetch"):
+                xs = np.asarray(sols.x)
+                objs = np.asarray(sols.obj)
+                conv = np.asarray(sols.converged)
 
             # Process rows in best-bound order (non-converged rows, which
             # need an eager HiGHS re-solve for a trusted bound, go last):
             # incumbents found by the round's strongest nodes then prune
             # the weaker batch-mates below, instead of going stale for a
             # round.
-            inc_updates = 0
-            order = sorted(range(len(popped)),
-                           key=lambda r: (not conv[r], float(objs[r])))
-            for row in order:
-                tr, nd = popped[row]
-                tr["nodes"] += 1
-                if conv[row]:
-                    x, obj, st = xs[row], float(objs[row]), "ok"
-                else:
-                    x, obj, st = _solve_node(lps[row], prefer_jax=False)
-                if st == "infeasible":
-                    continue
-                if obj >= tr["inc_mk"] * (1 - gap_tol):
-                    continue
-                cand, mk, cost = _expand_node(problem, nd, x, obj,
-                                              tr["cap"], tr["heap"],
-                                              tr["counter"])
-                if cand is not None and mk < tr["inc_mk"]:
-                    tr["incumbent"], tr["inc_mk"], tr["inc_cost"] = \
-                        cand, mk, cost
-                    propagate(mk, cost, cand)
-                    inc_updates += 1
+            with obs.span("milp.expand"):
+                inc_updates = 0
+                order = sorted(range(len(popped)),
+                               key=lambda r: (not conv[r], float(objs[r])))
+                for row in order:
+                    tr, nd = popped[row]
+                    tr["nodes"] += 1
+                    if conv[row]:
+                        x, obj, st = xs[row], float(objs[row]), "ok"
+                    else:
+                        x, obj, st = _solve_node(lps[row], prefer_jax=False)
+                    if st == "infeasible":
+                        continue
+                    if obj >= tr["inc_mk"] * (1 - gap_tol):
+                        continue
+                    cand, mk, cost = _expand_node(problem, nd, x, obj,
+                                                  tr["cap"], tr["heap"],
+                                                  tr["counter"])
+                    if cand is not None and mk < tr["inc_mk"]:
+                        tr["incumbent"], tr["inc_mk"], tr["inc_cost"] = \
+                            cand, mk, cost
+                        propagate(mk, cost, cand)
+                        inc_updates += 1
             round_span.set(incumbent_updates=inc_updates)
         obs.update(counters={"milp.rounds": 1, "milp.nodes": len(popped),
+                             "milp.batch_rows": batch_width,
                              "milp.incumbent_updates": inc_updates})
 
     wall = time.monotonic() - t0

@@ -15,6 +15,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import heuristics, milp
 from repro.core.problem import AllocationProblem
 
@@ -216,15 +217,21 @@ def milp_tradeoff_batched(problem: AllocationProblem, n_points: int = 8,
                   "newton_dtype", "mesh", "row_spec"):
             kw.pop(k, None)
         return milp_tradeoff(problem, n_points, backend=backend, **kw)
-    c_l, c_u, top = cost_bounds_batched(problem, **_bnb_kw(kw))
-    caps = np.linspace(c_l, max(c_u, c_l), n_points)
-    _, lbs, sols = relaxation_frontier(problem, caps, return_solutions=True,
-                                       **_stacked_solve_kw(kw))
-    lbs = _trusted_bounds(lbs, sols.converged)
-    xs = np.asarray(sols.x)
-    relax_allocs = [problem.split_node_x(xs[k])[0] for k in range(len(caps))]
-    points = _warm_sweep(problem, caps, lbs, relax_allocs, top,
-                         **_bnb_kw(kw))
+    with obs.span("pareto.sweep", n_points=n_points):
+        with obs.span("pareto.anchor"):
+            c_l, c_u, top = cost_bounds_batched(problem, **_bnb_kw(kw))
+        caps = np.linspace(c_l, max(c_u, c_l), n_points)
+        with obs.span("pareto.relaxation"):
+            _, lbs, sols = relaxation_frontier(problem, caps,
+                                               return_solutions=True,
+                                               **_stacked_solve_kw(kw))
+            lbs = _trusted_bounds(lbs, sols.converged)
+            xs = np.asarray(sols.x)
+            relax_allocs = [problem.split_node_x(xs[k])[0]
+                            for k in range(len(caps))]
+        with obs.span("pareto.bnb"):
+            points = _warm_sweep(problem, caps, lbs, relax_allocs, top,
+                                 **_bnb_kw(kw))
     points.append(TradeoffPoint(None, top.makespan, top.cost, top.alloc,
                                 dict(status=top.status, nodes=top.nodes,
                                      lb=top.lower_bound)))

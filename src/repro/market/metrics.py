@@ -32,7 +32,6 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro import obs
 from repro.market.simulator import EpisodeResult
 
 
@@ -100,11 +99,6 @@ def summarise(result: EpisodeResult, *,
                                 if r.replanned)),
         reset_wall_s=float(result.reset_wall_s),
         sla_penalty_rate=float(sla_penalty_rate))
-    # idempotent gauges (summarise may run several times per result,
-    # e.g. inside regret_table — gauges rewrite, they never double-count)
-    obs.gauge(f"market.{m.policy}.accrued_cost", m.accrued_cost)
-    obs.gauge(f"market.{m.policy}.slo_violation_s", m.slo_violation_s)
-    obs.gauge(f"market.{m.policy}.avg_makespan", m.avg_makespan)
     return m
 
 
@@ -225,8 +219,6 @@ def distributional_regret(costs: Dict[str, np.ndarray], *,
             float(np.quantile(r, 0.50)), float(np.quantile(r, 0.90)),
             float(np.quantile(r, alpha)), float(r[-k:].mean()),
             float(r[-1]))
-        obs.gauge(f"market.{name}.regret_cvar{int(alpha * 100)}",
-                  rep.cvar95)
         out[name] = rep
     return out
 
@@ -320,9 +312,6 @@ def regret(policy: EpisodeMetrics, oracle: EpisodeMetrics) -> RegretReport:
         slo_excess_s=policy.slo_violation_s - oracle.slo_violation_s,
         replans=policy.replans,
         replan_wall_s=policy.replan_wall_s)
-    obs.gauge(f"market.{rep.policy}.cost_regret", rep.cost_regret)
-    obs.gauge(f"market.{rep.policy}.makespan_regret", rep.makespan_regret)
-    obs.gauge(f"market.{rep.policy}.slo_excess_s", rep.slo_excess_s)
     return rep
 
 
@@ -357,7 +346,6 @@ def whole_horizon_regret(policy, oracle) -> RegretReport:
         slo_excess_s=policy.slo_violation_s - oracle.slo_violation_s,
         replans=policy.replans,
         replan_wall_s=getattr(policy, "replan_wall_s", 0.0))
-    obs.gauge(f"market.{rep.policy}.wh_cost_regret", rep.cost_regret)
     return rep
 
 

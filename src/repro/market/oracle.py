@@ -309,8 +309,6 @@ def whole_horizon_oracle(catalog, n, episode: ev.MarketEpisode, *,
         total_cost=total,
         n_intervals=n_int, n_columns=n_cols, n_lp_rows=len(nodes),
         lp_wall_s=lp_wall, dp_wall_s=_time.perf_counter() - t_start)
-    obs.gauge("market.dp_oracle.total_cost", traj.total_cost)
-    obs.gauge("market.dp_oracle.dp_wall_s", traj.dp_wall_s)
     return traj
 
 

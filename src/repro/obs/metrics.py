@@ -2,9 +2,9 @@
 with generic stack-based scoping.
 
 Unlike span tracing (off by default), the registry is ALWAYS on — it is
-the substrate the solver's Newton-row ledger, the serving layer's
-latency breakdown and the market's SLO/regret accounting all write to,
-and those consumers rely on counts being there after the fact.  Every
+the substrate the solver's Newton-row ledger, the B&B round counters
+and the serving layer's latency breakdown all write to, and those
+consumers rely on counts being there after the fact.  Every
 mutation takes one lock, so concurrent writers (the
 ``AllocationServer`` scheduler thread next to benchmark/main threads)
 never lose updates — the failure mode the old module-level

@@ -48,7 +48,8 @@ class _TraceState:
         self.lock = threading.Lock()
         self.events: List[SpanEvent] = []
         self.local = threading.local()
-        self.jax_profiler = False
+        # jax.profiler.TraceAnnotation while spans are mirrored, else None
+        self.annotation = None
 
 
 _STATE = _TraceState()
@@ -72,10 +73,13 @@ def enable(*, reset: bool = True, jax_profiler: bool = False) -> None:
     (used by the benchmark drivers' ``--profile-dir`` flag).
     """
     global _ENABLED
+    annotation = None
+    if jax_profiler:
+        from jax.profiler import TraceAnnotation as annotation
     with _STATE.lock:
         if reset:
             _STATE.events.clear()
-        _STATE.jax_profiler = bool(jax_profiler)
+        _STATE.annotation = annotation
     _ENABLED = True
 
 
@@ -83,7 +87,7 @@ def disable() -> None:
     """Turn span tracing off (collected spans are kept for export)."""
     global _ENABLED
     _ENABLED = False
-    _STATE.jax_profiler = False
+    _STATE.annotation = None
 
 
 class capture:
@@ -128,13 +132,10 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
-        if _STATE.jax_profiler:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._ann = TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        annotation = _STATE.annotation
+        if annotation is not None:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         self._depth = _depth()
         _STATE.local.depth = self._depth + 1
         self._t0 = time.perf_counter_ns()

@@ -88,6 +88,38 @@ def test_disabled_span_is_strict_noop():
     assert retained < 4096, f"disabled spans retained {retained} bytes"
 
 
+def test_profiler_mirror_resolves_the_annotation_once(monkeypatch):
+    """``enable(jax_profiler=True)`` looks ``TraceAnnotation`` up once:
+    every span then opens and closes one, and ``disable`` ends that."""
+    import jax.profiler
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    obs.enable(jax_profiler=True)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", None)
+    with obs.span("outer"):
+        with obs.span("inner"):
+            pass
+    obs.disable()
+    assert seen == [("enter", "outer"), ("enter", "inner"),
+                    ("exit", "inner"), ("exit", "outer")]
+    obs.enable()
+    with obs.span("plain"):
+        pass
+    assert len(seen) == 4
+    assert [e.name for e in obs.trace_events()] == ["plain"]
+
+
 def test_add_span_records_external_window():
     obs.enable()
     obs.add_span("lifecycle", 1_000, 5_000, tenant="t0")

@@ -1509,7 +1509,8 @@ def solve_node_lps_ladder(nodes, *, ladder_max: int, row_active=None,
     return LPSolution(*(f[:k] for f in sol))
 
 
-def warm_ladder(node, ladder_max: int, *, max_iters: int = _MAX_ITERS,
+def warm_ladder(node, ladder_max: int, *, widths=None,
+                max_iters: int = _MAX_ITERS,
                 tol: float = _TOL, linsolve: str = "xla",
                 compact: bool = False, chunk_iters=None,
                 newton_dtype: str = "float64",
@@ -1520,13 +1521,16 @@ def warm_ladder(node, ladder_max: int, *, max_iters: int = _MAX_ITERS,
     set, so the while-loop trip count is zero and each call costs one
     compile plus microseconds of run time — the same trick
     ``compact=True`` plays per-call in ``_warm_compact_ladder``).
+    ``widths`` warms only those widths instead (the lockstep B&B's two
+    dispatch rungs).
 
     After this returns, a server dispatching merged batches of this
     shape at any ladder width never compiles again:
     :func:`stacked_compile_count` is already final.  Returns the warmed
     widths (descending).
     """
-    widths = ladder_widths(ladder_max, mesh_n_shards(mesh, row_spec))
+    if widths is None:
+        widths = ladder_widths(ladder_max, mesh_n_shards(mesh, row_spec))
     for w in widths:
         with obs.span("lp.warm_width", width=w, linsolve=linsolve,
                       compact=compact):

@@ -15,8 +15,9 @@ The B&B exploits two observations about Eq. 4 (see DESIGN.md §2):
 Node LPs are solved by the jit-compiled JAX interior-point method
 (:mod:`repro.core.lp`); shapes are identical across nodes, so the jit
 cache holds a bounded, flat set of solver variants per problem size —
-one under the monolithic driver, one per power-of-two ladder width
-under the chunked ``compact=True`` driver
+one per dispatch width under the monolithic driver (a lockstep sweep
+has two, see :func:`solve_bnb_sweep`), and one per power-of-two ladder
+width under the chunked ``compact=True`` driver
 (``lp.stacked_compile_count`` tracks it).  Nodes whose IPM solve does
 not converge cleanly are re-solved with HiGHS (robust infeasibility
 certificates); the ``milp.host_resolves`` counter counts them.
@@ -313,6 +314,29 @@ def solve_bnb(problem: AllocationProblem, cost_cap: Optional[float] = None,
 # Lockstep batched B&B across a budget sweep
 # ---------------------------------------------------------------------------
 
+# (node-LP shape, rungs, solver configuration) whose dispatch rungs this
+# process has compiled
+_WARM_RUNGS: set = set()
+
+
+def _dispatch_rungs(batch_width: int) -> list:
+    """The widths a lockstep round may be dispatched at, descending: the
+    top two of the stacked solver's width ladder.  Each rung is one more
+    compiled program per node-LP shape, so there are no finer ones."""
+    return lpmod.ladder_widths(batch_width)[:2]
+
+
+def _warm_rungs(node, rungs: list, **solver) -> None:
+    """Compile every rung for ``node``'s shape and ``solver``
+    configuration, once per process, with all-retired calls
+    (:func:`repro.core.lp.warm_ladder`)."""
+    key = (tuple(np.shape(v) for v in node), tuple(rungs),
+           tuple(sorted(solver.items())))
+    if key not in _WARM_RUNGS:
+        lpmod.warm_ladder(node, rungs[0], widths=rungs, **solver)
+        _WARM_RUNGS.add(key)
+
+
 def solve_bnb_sweep(problem: AllocationProblem, caps,
                     *, node_limit: int = 2000, gap_tol: float = 1e-4,
                     time_limit_s: float = 120.0,
@@ -328,10 +352,17 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
                     newton_dtype: str = "float64") -> list:
     """Run one B&B tree per budget cap IN LOCKSTEP: each round pops the
     best open node from every active tree and solves all node relaxations
-    as a single fixed-width batched interior-point call
+    as a single batched interior-point call
     (:func:`repro.core.lp.solve_node_lps_stacked`).  Node shapes are
-    identical across trees, and closed trees are padded out of the batch,
-    so the batched solver compiles exactly once per sweep width.
+    identical across trees, and closed trees are padded out of the batch.
+    A round is dispatched at the smaller of two rungs that holds its
+    nodes: the batch width, or the next width below it on
+    :func:`repro.core.lp.ladder_widths` (16 and 8 at width 16).  A round
+    that pops half a batch or less thus pays half the rows: a vmapped
+    solve computes every row, retired or not, until its slowest row
+    converges.  The node-LP shape then has two compiled programs, both
+    compiled before the first round of the first sweep of that shape and
+    solver configuration in the process, so no later sweep compiles.
 
     Incumbents propagate across trees between rounds: an allocation found
     by one budget point seeds every other point whose budget it fits
@@ -355,7 +386,7 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
     ``linsolve`` picks the stacked IPM's Newton backend
     (:data:`repro.core.lp.LINSOLVES`).  With ``early_exit`` (default on)
     each round's batch is compacted: the popped nodes occupy the leading
-    rows and the fixed-width padding is marked inactive via the solver's
+    rows and the padding up to the rung is marked inactive via the solver's
     ``row_active`` mask, so retired rows are charged zero Newton
     iterations in the ``lp.newton_row_stats`` ledger instead of
     duplicating row 0's whole solve.  Note the ledger counts *useful*
@@ -396,6 +427,11 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
     if lower_bounds0 is None:
         lower_bounds0 = [None] * k
     mu, tau = problem.mu, problem.tau
+    rungs = _dispatch_rungs(batch_width)
+    solver = dict(linsolve=linsolve, compact=compact,
+                  chunk_iters=chunk_iters, newton_dtype=newton_dtype)
+    if len(rungs) > 1:
+        _warm_rungs(problem.node_lp(caps[0]), rungs, **solver)
 
     trees = []
     for cap, warm, lb0 in zip(caps, warm_allocs, lower_bounds0):
@@ -480,27 +516,24 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
             break
 
         rounds += 1
+        width = min(w for w in rungs if w >= len(popped))
         with obs.span("milp.round", round=rounds, popped=len(popped),
-                      width=batch_width) as round_span:
+                      width=batch_width, dispatch_width=width) as round_span:
             with obs.span("milp.assemble"):
                 lps = [problem.node_lp(tr["cap"], nd["b0"], nd["b1"],
                                        nd["d_lb"], nd["d_ub"])
                        for tr, nd in popped]
-                # fixed batch width: pad with row 0 so jit compiles once
-                # per sweep.  lp_tol ~ 1e-7 (vs the 1e-9 reference
-                # default): node solves only need bounding accuracy well
-                # inside gap_tol, and the whole batch iterates until its
-                # SLOWEST member converges.
-                batch = lps + [lps[0]] * (batch_width - len(lps))
+                # pad with row 0 to the rung, whose program is compiled.
+                # lp_tol ~ 1e-7 (vs the 1e-9 reference default): node
+                # solves only need bounding accuracy well inside gap_tol,
+                # and the whole batch iterates until its SLOWEST member
+                # converges.
+                batch = lps + [lps[0]] * (width - len(lps))
                 active = None
                 if early_exit:
-                    active = np.arange(batch_width) < len(lps)
+                    active = np.arange(width) < len(lps)
             sols = lpmod.solve_node_lps_stacked(batch, tol=lp_tol,
-                                                linsolve=linsolve,
-                                                row_active=active,
-                                                compact=compact,
-                                                chunk_iters=chunk_iters,
-                                                newton_dtype=newton_dtype)
+                                                row_active=active, **solver)
             with obs.span("milp.fetch"):
                 xs = np.asarray(sols.x)
                 objs = np.asarray(sols.obj)
@@ -537,6 +570,7 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
             round_span.set(incumbent_updates=inc_updates)
         obs.update(counters={"milp.rounds": 1, "milp.nodes": len(popped),
                              "milp.batch_rows": batch_width,
+                             "milp.dispatch_rows": width,
                              "milp.incumbent_updates": inc_updates})
 
     wall = time.monotonic() - t0

@@ -175,8 +175,10 @@ class WarmMILPPolicy(Policy):
     relaxation call bounds every budget point, the previous allocation
     (masked to live slots) and the relaxed allocations seed incumbents,
     and dead slots are pinned.  ``batch_width`` is locked to ``n_caps``
-    so the relaxation and the node sweep share one compiled shape — the
-    whole episode runs on a single stacked-solver compilation.
+    so the relaxation and the node sweep's full rounds share one
+    compiled shape; a round that the next ladder width below ``n_caps``
+    holds runs at that width (4 at ``n_caps`` 5).  Both widths compile
+    on the first replan's sweep, so the episode compiles nothing after it.
     """
     n_caps: int = 5
     node_limit: int = 120

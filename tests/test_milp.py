@@ -358,3 +358,110 @@ def test_degenerate_warm_alloc_is_projected():
     assert abs(mk - r.makespan) <= 1e-6 * max(mk, 1.0)
     ref = milp.solve_bnb(p, None, node_limit=100, time_limit_s=30)
     assert r.makespan >= ref.makespan * (1 - 1e-3)
+
+
+# ---------------------------------------------------------------------------
+# Dispatch rungs: a half-live round runs at the lower rung
+# ---------------------------------------------------------------------------
+
+def _traced_sweep(p, caps, **kw):
+    """One sweep with spans on: its results, spans and counter deltas."""
+    from repro import obs
+    obs.enable()
+    try:
+        with obs.scope() as scoped:
+            res = milp.solve_bnb_sweep(p, caps, **kw)
+    finally:
+        obs.disable()
+    events = sorted(obs.trace_events(), key=lambda e: e.ts_ns)
+    obs.clear_trace()
+    return res, events, scoped["counters"]
+
+
+@pytest.mark.parametrize("batch_width, rungs", [(8, [8, 4]), (5, [5, 4])])
+def test_sweep_dispatches_half_live_rounds_at_the_lower_rung(batch_width,
+                                                             rungs):
+    """A round whose popped nodes fit the lower rung is solved at that
+    width; ``milp.round``'s ``width`` and ``milp.batch_rows`` still read
+    the sweep's batch width, ``dispatch_width`` and
+    ``milp.dispatch_rows`` the width paid."""
+    assert milp._dispatch_rungs(batch_width) == rungs
+    p = random_problem(40)
+    c_l = float(p.single_platform_cost().min())
+    caps = np.linspace(c_l, c_l * 3, 4)
+    kw = dict(node_limit=6, time_limit_s=np.inf, batch_width=batch_width)
+    milp.solve_bnb_sweep(p, caps, **kw)                 # compile
+    _, events, counters = _traced_sweep(p, caps, **kw)
+    rounds = [e for e in events if e.name == "milp.round"]
+    solves = [e for e in events if e.name == "lp.solve_stacked"]
+    assert len(rounds) == len(solves) == counters["milp.rounds"]
+    for r, s in zip(rounds, solves):
+        want = rungs[1] if r.attrs["popped"] <= rungs[1] else rungs[0]
+        assert r.attrs["width"] == batch_width
+        assert r.attrs["dispatch_width"] == s.attrs["width"] == want
+    assert any(r.attrs["dispatch_width"] == rungs[1] for r in rounds)
+    assert counters["milp.batch_rows"] == batch_width * len(rounds)
+    assert counters["milp.dispatch_rows"] == sum(
+        r.attrs["dispatch_width"] for r in rounds)
+    assert (counters["milp.nodes"] <= counters["milp.dispatch_rows"]
+            < counters["milp.batch_rows"])
+
+
+def test_sweep_rungs_do_not_change_the_search(monkeypatch):
+    """Dispatching at the lower rung changes only the padding: the same
+    nodes are popped and every budget point ends with the same
+    allocation, makespan, cost, bound, status and node count as at the
+    full width alone."""
+    p = random_problem(41)
+    c_l = float(p.single_platform_cost().min())
+    caps = np.linspace(c_l, c_l * 3, 4)
+    kw = dict(node_limit=20, time_limit_s=np.inf)
+    laddered = milp.solve_bnb_sweep(p, caps, **kw)
+    monkeypatch.setattr(milp, "_dispatch_rungs", lambda width: [width])
+    full = milp.solve_bnb_sweep(p, caps, **kw)
+    for a, b in zip(laddered, full):
+        assert a.status == b.status and a.nodes == b.nodes
+        if a.alloc is None:
+            assert b.alloc is None
+            continue
+        np.testing.assert_array_equal(a.alloc, b.alloc)
+        assert a.makespan == b.makespan
+        assert a.cost == b.cost
+        assert a.lower_bound == b.lower_bound
+
+
+def test_sweep_of_a_known_shape_compiles_nothing():
+    """The first sweep of a node-LP shape compiles both rungs, even one
+    whose rounds use only the lower rung (a node limit of 1 leaves one
+    round of 4 roots); a sweep of another problem of that shape that
+    uses both rungs then compiles nothing."""
+    from repro.core import lp
+    count = None
+    for seed, node_limit in ((52, 1), (53, 6)):
+        p = random_problem(seed, mu=3, tau=8)
+        c_l = float(p.single_platform_cost().min())
+        caps = np.linspace(c_l, c_l * 3, 4)
+        _, events, _ = _traced_sweep(p, caps, node_limit=node_limit,
+                                     time_limit_s=np.inf)
+        count = lp.stacked_compile_count() if count is None else count
+    assert lp.stacked_compile_count() == count
+    assert {e.attrs["dispatch_width"] for e in events
+            if e.name == "milp.round"} == {8, 4}
+    assert not any(e.name == "lp.warm_width" for e in events)
+
+
+def test_width_one_sweep_compiles_one_width():
+    """At batch width 1 there is one rung: the sweep compiles one
+    program for its node-LP shape and warms no other width."""
+    from repro.core import lp
+    p = random_problem(54, mu=2, tau=9)
+    c_l = float(p.single_platform_cost().min())
+    caps = np.linspace(c_l, c_l * 3, 3)
+    assert milp._dispatch_rungs(1) == [1]
+    count = lp.stacked_compile_count()
+    _, events, _ = _traced_sweep(p, caps, node_limit=4, time_limit_s=np.inf,
+                                 batch_width=1)
+    assert lp.stacked_compile_count() == count + 1
+    assert {e.attrs["width"] for e in events
+            if e.name == "lp.solve_stacked"} == {1}
+    assert not any(e.name == "lp.warm_width" for e in events)

@@ -76,24 +76,30 @@ def scalarised(problem: AllocationProblem, cost_weight: float) -> np.ndarray:
 
 def min_min(problem: AllocationProblem) -> np.ndarray:
     """Braun et al. Min-min list scheduler with WHOLE task assignment
-    (binary A) — the classic heuristic baseline for atomic tasks."""
+    (binary A) — the classic heuristic baseline for atomic tasks.
+
+    Each step places the task with the least earliest completion time
+    (ties: lowest task, then lowest platform).  A task's earliest
+    completion moves only when its best platform's ready time does, so
+    a step recomputes just those tasks: O(tau) numpy steps in all."""
     mu, tau = problem.mu, problem.tau
+    bn, gm = problem.beta_n, problem.gamma
     ready = np.zeros(mu)                       # platform busy-until
     alloc = np.zeros((mu, tau))
-    remaining = set(range(tau))
-    used = np.zeros(mu, dtype=bool)
-    while remaining:
-        best = None
-        for j in remaining:
-            ect = ready + problem.beta_n[:, j] + problem.gamma[:, j]
-            i = int(np.argmin(ect))
-            if best is None or ect[i] < best[0]:
-                best = (ect[i], i, j)
-        _, i, j = best
-        ready[i] += problem.beta_n[i, j] + problem.gamma[i, j]
-        used[i] = True
+    ect = (ready[:, None] + bn) + gm
+    best_i = ect.argmin(axis=0)                # each task's best platform
+    best = ect[best_i, np.arange(tau)]         # and its completion time
+    for _ in range(tau):
+        j = int(np.argmin(best))
+        i = int(best_i[j])
         alloc[i, j] = 1.0
-        remaining.remove(j)
+        best[j] = np.inf
+        ready[i] += bn[i, j] + gm[i, j]
+        stale = np.flatnonzero((best_i == i) & np.isfinite(best))
+        if stale.size:
+            col = (ready[:, None] + bn[:, stale]) + gm[:, stale]
+            best_i[stale] = col.argmin(axis=0)
+            best[stale] = col[best_i[stale], np.arange(stale.size)]
     return alloc
 
 

@@ -1,4 +1,4 @@
-"""Dense primal-dual interior-point LP solver in JAX.
+"""Primal-dual interior-point LP solver in JAX.
 
 Solves   min c.x   s.t.  A_eq x = b_eq,  G x <= h,  lb <= x <= ub
 via Mehrotra's predictor-corrector method on the bounded standard form
@@ -10,6 +10,13 @@ w for x <= u), so the normal-equation matrix stays (m x m) with
 m = #rows(A_eq) + #rows(G) — this is what makes the B&B node solves cheap
 (DESIGN.md §2).  jit-compiled with ``lax.while_loop``; ``vmap``-able across a
 batch of right-hand sides (the epsilon-constraint cost grid).
+
+The iteration runs against a constraint operator: :class:`DenseOp` holds a
+generic LP's matrix; :class:`NodeOp` holds a node LP by its structure
+(``tau`` placement rows, each share in exactly one, and a border of
+2*mu+1 rows), so each Newton system reduces to a (2*mu+1)-square Schur
+complement and no dense node matrix is ever built (docs/solver.md "Node
+LPs: the operator and the border").
 
 Two stacked execution drivers share the same per-iteration math:
 
@@ -62,9 +69,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.problem import NodeRows, node_border
 
 _ETA = 0.99995          # fraction-to-boundary
-_MAX_ITERS = 100
+# Node LPs need more iterations as the book grows (~25 at 128 tasks,
+# 45–150 at 3,072–4,096): below the cap, such a row would end
+# unconverged and go to the host's LP solver
+_MAX_ITERS = 200
 _TOL = 1e-9
 _INF_UB = 1e30          # finite stand-in for +inf upper bounds
 _CHUNK_ITERS = 8        # default chunk length of the compacted driver
@@ -182,7 +193,7 @@ class LPSolution(NamedTuple):
     iters: jnp.ndarray
     primal_res: jnp.ndarray
     dual_res: jnp.ndarray
-    gap: jnp.ndarray
+    gap: jnp.ndarray        # max(mean complementarity, gap / (1+|c.x|))
 
     @property
     def converged(self):
@@ -190,15 +201,158 @@ class LPSolution(NamedTuple):
                 & (self.gap < 1e-6))
 
 
-class _StdForm(NamedTuple):
+class DenseOp(NamedTuple):
+    """A standard-form constraint matrix held densely (generic LPs): the
+    operator interface the interior point runs against."""
     a: jnp.ndarray
+
+    @property
+    def shape(self):
+        return self.a.shape
+
+    @property
+    def dtype(self):
+        return self.a.dtype
+
+    def matvec(self, x):
+        return self.a @ x
+
+    def rmatvec(self, y):
+        return self.a.T @ y
+
+    def col_absmax(self):
+        return jnp.abs(self.a).max(axis=0)
+
+    def row_absmax(self):
+        return jnp.abs(self.a).max(axis=1)
+
+    def scale_cols(self, s):
+        return DenseOp(self.a * s[None, :])
+
+    def scale_rows(self, s):
+        return DenseOp(self.a * s[:, None])
+
+    def normal_solve(self, d, rhs, linsolve: str, newton_dtype: str,
+                     ridge: float):
+        """``(A diag(d) A^T) dy = rhs`` -> ``(dy, rel_resid)``."""
+        m_mat = (self.a * d[None, :]) @ self.a.T
+        if ridge:
+            m_mat = m_mat + ridge * jnp.eye(self.a.shape[0],
+                                            dtype=self.a.dtype)
+        return _newton_solve(linsolve, newton_dtype, m_mat, rhs)
+
+
+class NodeOp(NamedTuple):
+    """The standard-form constraint matrix of a node LP, held by its
+    structure.  Rows are [E; B]: one placement row per task, then the
+    border of 2*mu (+1 with a budget) latency, quanta and budget rows.
+    Columns are the mu*tau shares A (platform-major), then the small
+    block: D (mu), F_L and a slack per border row.
+
+    E has one entry per share column, ``e[i, j]`` in row j; the border
+    weighs platform i's shares by ``p[k, i] * w[i, j]`` and the small
+    columns by ``bs``.  So ``E Theta^-1 E^T`` is diagonal, and a Newton
+    step reduces to the Schur complement on the border,
+    ``S = M_B - C^T D_E^-1 C``, of size 2*mu+1: the first tau steps of a
+    dense Cholesky of the normal matrix in this row order, regrouped.
+    Row and column scales are diagonal and keep the structure."""
+    e: jnp.ndarray              # (mu, tau)
+    w: jnp.ndarray              # (mu, tau)
+    p: jnp.ndarray              # (m_b, mu)
+    bs: jnp.ndarray             # (m_b, mu + 1 + m_b)
+
+    @classmethod
+    def of(cls, coef, rho, pi, m_b: int) -> "NodeOp":
+        """The unscaled standard form of a node LP's rows
+        (:class:`repro.core.problem.NodeRows`) with ``m_b`` border rows:
+        2*mu, or 2*mu + 1 with the budget row."""
+        p, small = node_border(rho, pi, m_b, jnp)
+        bs = jnp.concatenate([small, jnp.eye(m_b, dtype=small.dtype)],
+                             axis=1)
+        return cls(jnp.ones_like(coef), coef, p, bs)
+
+    @property
+    def shape(self):
+        mu, tau = self.e.shape
+        return (tau + self.p.shape[0], mu * tau + self.bs.shape[1])
+
+    @property
+    def dtype(self):
+        return self.e.dtype
+
+    def _split(self, v):
+        mu, tau = self.e.shape
+        return v[:mu * tau].reshape(mu, tau), v[mu * tau:]
+
+    def matvec(self, x):
+        xa, xs = self._split(x)
+        return jnp.concatenate([(self.e * xa).sum(axis=0),
+                                self.p @ (self.w * xa).sum(axis=1)
+                                + self.bs @ xs])
+
+    def rmatvec(self, y):
+        tau = self.e.shape[1]
+        ye, yb = y[:tau], y[tau:]
+        ga = self.e * ye[None, :] + self.w * (self.p.T @ yb)[:, None]
+        return jnp.concatenate([ga.ravel(), self.bs.T @ yb])
+
+    def col_absmax(self):
+        pa = jnp.abs(self.p).max(axis=0)
+        ca = jnp.maximum(jnp.abs(self.e), jnp.abs(self.w) * pa[:, None])
+        return jnp.concatenate([ca.ravel(), jnp.abs(self.bs).max(axis=0)])
+
+    def row_absmax(self):
+        wa = jnp.abs(self.w).max(axis=1)
+        rb = jnp.maximum((jnp.abs(self.p) * wa[None, :]).max(axis=1),
+                         jnp.abs(self.bs).max(axis=1))
+        return jnp.concatenate([jnp.abs(self.e).max(axis=0), rb])
+
+    def scale_cols(self, s):
+        sa, ss = self._split(s)
+        return NodeOp(self.e * sa, self.w * sa, self.p,
+                      self.bs * ss[None, :])
+
+    def scale_rows(self, s):
+        tau = self.e.shape[1]
+        se, sb = s[:tau], s[tau:]
+        return NodeOp(self.e * se[None, :], self.w, self.p * sb[:, None],
+                      self.bs * sb[:, None])
+
+    def normal_solve(self, d, rhs, linsolve: str, newton_dtype: str,
+                     ridge: float):
+        """``(A diag(d) A^T) dy = rhs`` -> ``(dy, rel_resid)`` through the
+        border: eliminate the diagonal task block ``D_E``, solve
+        ``S dy_B = r_B - C^T D_E^-1 r_E`` with :func:`_newton_solve`
+        (``linsolve`` and ``newton_dtype`` act on S), back-substitute
+        ``dy_E = D_E^-1 (r_E - C dy_B)``.  ``C = G^T P^T`` with
+        ``G = e d w``; ``rel_resid`` is S's."""
+        da, ds = self._split(d)
+        tau = self.e.shape[1]
+        d_e = (self.e * self.e * da).sum(axis=0)
+        g = self.e * da * self.w
+        if ridge:
+            d_e = d_e + ridge
+        gd = g / d_e[None, :]
+        k = jnp.diag((self.w * self.w * da).sum(axis=1)) - gd @ g.T
+        s = self.p @ k @ self.p.T + (self.bs * ds[None, :]) @ self.bs.T
+        if ridge:
+            s = s + ridge * jnp.eye(s.shape[0], dtype=s.dtype)
+        r_e, r_b = rhs[:tau], rhs[tau:]
+        dy_b, rel = _newton_solve(linsolve, newton_dtype, s,
+                                  r_b - self.p @ (gd @ r_e))
+        dy_e = (r_e - g.T @ (self.p.T @ dy_b)) / d_e
+        return jnp.concatenate([dy_e, dy_b]), rel
+
+
+class _StdForm(NamedTuple):
+    a: object               # DenseOp or NodeOp
     b: jnp.ndarray
     c: jnp.ndarray
     u: jnp.ndarray          # upper bounds, _INF_UB where unbounded
     n_orig: int
     lb: jnp.ndarray         # original lower bounds (for un-shifting)
     row_scale: jnp.ndarray
-    col_scale: jnp.ndarray
+    col_scale: jnp.ndarray  # un-standardising: x = col_scale * x' + lb
 
 
 class _IPMCarry(NamedTuple):
@@ -217,7 +371,31 @@ class _IPMCarry(NamedTuple):
 
 
 def _standardise(c, a_eq, b_eq, g, h, lb, ub) -> _StdForm:
-    """Shift lb to 0, add slacks for G rows, row+column equilibrate.
+    """Standard form of an LP: shift lb to 0, add slacks for the
+    inequality rows, row+column equilibrate (:func:`_equilibrate`).  The
+    rows come densely (:class:`DenseOp`), or as a node LP's
+    :class:`~repro.core.problem.NodeRows` in ``a_eq`` with ``b_eq`` and
+    ``g`` None (:class:`NodeOp`: every task row's rhs is 1, ``h`` holds
+    the border's), the same arithmetic on the same rows."""
+    m_in = h.shape[0]
+    # shift x' = x - lb
+    if isinstance(a_eq, NodeRows):
+        op = NodeOp.of(a_eq.coef, a_eq.rho, a_eq.pi, m_in)
+        b = (jnp.concatenate([jnp.ones((a_eq.coef.shape[1],), h.dtype), h])
+             - op.matvec(jnp.concatenate([lb, jnp.zeros((m_in,),
+                                                        lb.dtype)])))
+    else:
+        b = jnp.concatenate([b_eq - a_eq @ lb, h - g @ lb])
+        op = DenseOp(jnp.block([
+            [a_eq, jnp.zeros((a_eq.shape[0], m_in), a_eq.dtype)],
+            [g, jnp.eye(m_in, dtype=g.dtype)],
+        ]))
+    return _equilibrate(op, b, c, lb, ub, m_in)
+
+
+def _equilibrate(op, b, c, lb, ub, m_in: int) -> _StdForm:
+    """Bounds and objective of the shifted standard form, then two-sided
+    equilibration of ``op``.
 
     The node LPs mix coefficients spanning ~8 orders of magnitude
     (beta*N in the hundreds of seconds next to unit allocation rows);
@@ -225,30 +403,30 @@ def _standardise(c, a_eq, b_eq, g, h, lb, ub) -> _StdForm:
     around 1e-5 residuals.
     """
     n = c.shape[0]
-    m_eq, m_in = a_eq.shape[0], g.shape[0]
-    # shift x' = x - lb
-    b_eq2 = b_eq - a_eq @ lb
-    h2 = h - g @ lb
     # variables pinned by lb == ub (e.g. dead-platform allocations in
     # scenario solves) keep a sliver of interior so the IPM stays finite
     u = jnp.where(jnp.isfinite(ub), jnp.maximum(ub - lb, 1e-9), _INF_UB)
-    a = jnp.block([
-        [a_eq, jnp.zeros((m_eq, m_in), a_eq.dtype)],
-        [g, jnp.eye(m_in, dtype=g.dtype)],
-    ])
-    b = jnp.concatenate([b_eq2, h2])
     c2 = jnp.concatenate([c, jnp.zeros((m_in,), c.dtype)])
     u2 = jnp.concatenate([u, jnp.full((m_in,), _INF_UB, u.dtype)])
     # column equilibration: x = col_scale * x'
-    col_scale = 1.0 / jnp.clip(jnp.abs(a).max(axis=0), 1e-8, 1e8)
-    a = a * col_scale[None, :]
+    col_scale = 1.0 / jnp.clip(op.col_absmax(), 1e-8, 1e8)
+    op = op.scale_cols(col_scale)
     c2 = c2 * col_scale
     u2 = jnp.where(u2 < _INF_UB * 0.5, u2 / col_scale, _INF_UB)
     # row equilibration
-    row_scale = 1.0 / jnp.maximum(jnp.abs(a).max(axis=1), 1e-12)
-    a = a * row_scale[:, None]
+    row_scale = 1.0 / jnp.maximum(op.row_absmax(), 1e-12)
+    op = op.scale_rows(row_scale)
     b = b * row_scale
-    return _StdForm(a, b, c2, u2, n, lb, row_scale, col_scale)
+    # a pinned variable's sliver maps back to its bound: left at up to
+    # 1e-9, a share fixed to 0 would still count in its platform's rows
+    pinned = jnp.concatenate([ub - lb < 1e-9, jnp.zeros((m_in,), bool)])
+    col_scale = jnp.where(pinned, 0.0, col_scale)
+    return _StdForm(op, b, c2, u2, n, lb, row_scale, col_scale)
+
+
+# name of the programs that solve node LPs: their device ops sit in
+# module "jit_node_ipm" of a profiler trace
+NODE_IPM = "node_ipm"
 
 
 def _step_len(v, dv, finite=None):
@@ -265,7 +443,8 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
     per-iteration ``make_body(newton_dtype)`` and the residual ``report``
     — shared verbatim by the monolithic ``_solve_std`` while-loop and the
     chunked driver's per-chunk stepper, so both drivers run the exact
-    same row math."""
+    same row math.  ``a`` is the constraint operator (:class:`DenseOp`
+    or :class:`NodeOp`)."""
     m, n = a.shape
     dtype = a.dtype
     has_ub = u < _INF_UB * 0.5
@@ -293,14 +472,18 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
                          ~jnp.asarray(active, dtype=bool), false, false)
 
     def residuals(x, y, z, w, s):
-        r_p = b - a @ x
-        r_d = c - a.T @ y - z + w
+        r_p = b - a.matvec(x)
+        r_d = c - a.rmatvec(y) - z + w
         r_u = jnp.where(has_ub, u - x - s, 0.0)
         return r_p, r_d, r_u
 
     def mu_of(x, z, s, w):
         denom = n + has_ub.sum()
         return (x @ z + jnp.where(has_ub, s * w, 0.0).sum()) / denom
+
+    def rel_gap_of(x, z, s, w):
+        return ((x @ z + jnp.where(has_ub, s * w, 0.0).sum())
+                / (1.0 + jnp.abs(c @ x)))
 
     def make_body(newton_dtype: str, graduated: bool = False):
         """``graduated`` marks the float64 phase of the mixed-precision
@@ -316,16 +499,15 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
             # rhs of normal equations
             rhat = (r_d - rc_xz / x
                     + jnp.where(has_ub, (rc_sw - w * r_u) / s, 0.0))
-            # no ridge: the standard form has full row rank (a slack per
-            # G row, disjoint task rows), and an absolute ridge swamps
-            # the tiny pivots of degenerate rows near a tight budget —
-            # with one, the Cholesky path stalls where LU got through
-            m_mat = (a * theta_inv[None, :]) @ a.T
-            if f32:
-                m_mat = m_mat + 1e-11 * jnp.eye(m, dtype=dtype)
-            rhs = r_p + a @ (theta_inv * rhat)
-            dy, rel = _newton_solve(row_linsolve, newton_dtype, m_mat, rhs)
-            dx = theta_inv * (a.T @ dy - rhat)
+            # no ridge on the float64 path: the standard form has full
+            # row rank (a slack per G row, disjoint task rows), and an
+            # absolute ridge swamps the tiny pivots of degenerate rows
+            # near a tight budget — with one, the Cholesky path stalls
+            # where LU got through
+            rhs = r_p + a.matvec(theta_inv * rhat)
+            dy, rel = a.normal_solve(theta_inv, rhs, row_linsolve,
+                                     newton_dtype, 1e-11 if f32 else 0.0)
+            dx = theta_inv * (a.rmatvec(dy) - rhat)
             dz = (rc_xz - z * dx) / x
             ds = jnp.where(has_ub, r_u - dx, 0.0)
             dw = jnp.where(has_ub, (rc_sw - w * ds) / s, 0.0)
@@ -381,9 +563,13 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
             # convergence check
             r_p2, r_d2, _ = residuals(x, y, z, w, s)
             mu2 = mu_of(x, z, s, w)
+            # the mean complementarity alone lets the objective's error
+            # grow with the number of columns (to ~1e-5 at 4,096 tasks
+            # for mu < 1e-7): the duality gap relative to the objective
+            # must pass the tolerance too
             done = ((jnp.linalg.norm(r_p2) / b_norm < tol)
                     & (jnp.linalg.norm(r_d2) / c_norm < tol)
-                    & (mu2 < tol))
+                    & (mu2 < tol) & (rel_gap_of(x, z, s, w) < tol))
             if f32:
                 bad = (carry.bad | (~ok) | (rel_a > _F32_REFINE_RTOL)
                        | (rel_c > _F32_REFINE_RTOL))
@@ -393,16 +579,28 @@ def _ipm_ops(a, b, c, u, tol, linsolve):
                 it32 = carry.it32 + 1
             else:
                 bad, grad, it32 = carry.bad, carry.grad, carry.it32
+                # a rejected float64 step leaves the iterate as it was,
+                # so every later iteration would repeat it (an infeasible
+                # node diverges until its Newton system breaks down): the
+                # row stops where it is, and ``report`` says whether that
+                # is close enough to count as converged
+                done = done | ~ok
             return _IPMCarry(x, y, z, w, s, carry.it + 1, it32, done, bad,
                              grad)
 
         return body
 
     def report(carry: _IPMCarry):
-        r_p, r_d, _ = residuals(carry.x, carry.y, carry.z, carry.w, carry.s)
-        mu = mu_of(carry.x, carry.z, carry.s, carry.w)
+        """Relative primal and dual residuals, and the larger of the mean
+        complementarity and the duality gap relative to the objective: a
+        row that stops short of ``tol`` (cap or rejected step) counts as
+        converged (:attr:`LPSolution.converged`) only if its objective is
+        that close too."""
+        x, z, s, w = carry.x, carry.z, carry.s, carry.w
+        r_p, r_d, _ = residuals(x, carry.y, z, w, s)
         return (jnp.linalg.norm(r_p) / b_norm,
-                jnp.linalg.norm(r_d) / c_norm, mu)
+                jnp.linalg.norm(r_d) / c_norm,
+                jnp.maximum(mu_of(x, z, s, w), rel_gap_of(x, z, s, w)))
 
     return init, make_body, report
 
@@ -450,42 +648,59 @@ def _solve_std(a, b, c, u, tol=_TOL, active=True, *,
     return (carry.x, carry.y, carry.it, rp, rd, mu, carry.it32, carry.bad)
 
 
+def _base_ndims(a_eq, b_eq, g) -> tuple:
+    """The unbatched ndim of each of an LP's seven arguments, a pytree
+    like them: a node LP's :class:`~repro.core.problem.NodeRows` in
+    ``a_eq`` (``b_eq`` and ``g`` None), or dense rows."""
+    if isinstance(a_eq, NodeRows):
+        if b_eq is not None or g is not None:
+            raise ValueError("node rows (NodeRows in a_eq) take b_eq=None "
+                             "and g=None")
+        return (1, NodeRows(2, 1, 1), None, None, 1, 1, 1)
+    return (1, 2, 1, 2, 1, 1, 1)
+
+
+def _on_device(args, dt):
+    """An LP's seven arguments as ``dt`` device arrays (None stays)."""
+    return jax.tree.map(lambda v: jnp.asarray(v, dt), tuple(args))
+
+
 def solve_lp(c, a_eq, b_eq, g, h, lb, ub, *, max_iters: int = _MAX_ITERS,
              linsolve: str = "xla", newton_dtype: str = "float64"
              ) -> LPSolution:
-    """Solve the bounded LP.  All inputs numpy/JAX arrays; float64 advised."""
+    """Solve the bounded LP.  All inputs numpy/JAX arrays; float64 advised.
+    The rows come densely, or as a node LP's :class:`NodeRows` in ``a_eq``
+    (``b_eq`` and ``g`` None), solved through :class:`NodeOp`."""
     dt = jnp.float64
     newton_dtype = _canon_newton_dtype(newton_dtype)
-    std = _standardise(jnp.asarray(c, dt), jnp.asarray(a_eq, dt),
-                       jnp.asarray(b_eq, dt), jnp.asarray(g, dt),
-                       jnp.asarray(h, dt), jnp.asarray(lb, dt),
-                       jnp.asarray(ub, dt))
+    _base_ndims(a_eq, b_eq, g)          # refuses node rows with b_eq or g
+    arrs = _on_device((c, a_eq, b_eq, g, h, lb, ub), dt)
+    if isinstance(a_eq, NodeRows):
+        solve = _single_node_solver(max_iters, linsolve, newton_dtype)
+        return solve(jnp.asarray(_TOL, dt), jnp.asarray(True), *arrs)[0]
+    std = _standardise(*arrs)
     x, y, it, rp, rd, gap, _, _ = _solve_std(std.a, std.b, std.c, std.u,
                                              max_iters=max_iters,
                                              linsolve=linsolve,
                                              newton_dtype=newton_dtype)
     x_orig = x[:std.n_orig] * std.col_scale[:std.n_orig] + std.lb
     y_orig = y * std.row_scale
-    obj = jnp.asarray(c, dt) @ x_orig
+    obj = arrs[0] @ x_orig
     return LPSolution(x_orig, obj, y_orig, it, rp, rd, gap)
 
 
 def solve_node_lp(node, *, max_iters: int = _MAX_ITERS,
                   linsolve: str = "xla", newton_dtype: str = "float64"
                   ) -> LPSolution:
-    """Convenience wrapper for :class:`repro.core.problem.NodeLP`."""
-    return solve_lp(node.c, node.a_eq, node.b_eq, node.g, node.h,
-                    node.lb, node.ub, max_iters=max_iters, linsolve=linsolve,
+    """Solve one :class:`repro.core.problem.NodeLP` through its border."""
+    return solve_lp(node.c, node.rows, None, None, node.h, node.lb,
+                    node.ub, max_iters=max_iters, linsolve=linsolve,
                     newton_dtype=newton_dtype)
 
 
 # ---------------------------------------------------------------------------
 # Batched engine
 # ---------------------------------------------------------------------------
-# Base (unbatched) ndim of each LP array, in solve_lp argument order.
-_BASE_NDIM = (1, 2, 1, 2, 1, 1, 1)          # c, a_eq, b_eq, g, h, lb, ub
-
-
 # -- mesh helpers (row-sharded megabatches; docs/solver.md "Sharded
 # megabatches").  LP rows are embarrassingly data-parallel, so sharding
 # is pure row partitioning: each shard runs the SAME driver on its own
@@ -566,21 +781,47 @@ def _registered_jit(key, build):
     return fn
 
 
+def _is_node(axes) -> bool:
+    """Whether a batching pattern (the seven arguments' axes) is a node
+    LP's: its ``a_eq`` entry is a :class:`NodeRows`."""
+    return isinstance(axes[1], NodeRows)
+
+
+def _named(fn, node: bool, suffix: str = ""):
+    """Name a program that solves node LPs so its device ops are told
+    apart in a trace (module ``jit_node_ipm*``); others keep their
+    names."""
+    if node:
+        fn.__name__ = NODE_IPM + suffix
+    return fn
+
+
 def _stacked_one(max_iters: int, linsolve: str, newton_dtype: str):
     """One row of the monolithic stacked solve: standardise, run the IPM
     to convergence, un-standardise.  Shared by the single-device
-    jit(vmap) driver and the per-shard body of the sharded driver."""
-    def one(tol, active, c, a_eq, b_eq, g, h, lb, ub):
-        std = _standardise(c, a_eq, b_eq, g, h, lb, ub)
+    jit(vmap) driver and the per-shard body of the sharded driver.  The
+    row's seven arguments come as :func:`_standardise` takes them,
+    objective first."""
+    def one(tol, active, *arrs):
+        std = _standardise(*arrs)
         x, y, it, rp, rd, gap, it32, bad = _solve_std(
             std.a, std.b, std.c, std.u, tol, active,
             max_iters=max_iters, linsolve=linsolve,
             newton_dtype=newton_dtype)
         xo = x[:std.n_orig] * std.col_scale[:std.n_orig] + std.lb
-        return (LPSolution(xo, c @ xo, y * std.row_scale, it, rp, rd,
+        return (LPSolution(xo, arrs[0] @ xo, y * std.row_scale, it, rp, rd,
                            gap), it32, bad)
 
     return one
+
+
+@functools.lru_cache(maxsize=None)
+def _single_node_solver(max_iters: int, linsolve: str, newton_dtype: str):
+    """jit of one node LP's whole solve, standardisation included: a
+    single node LP costs one dispatch instead of the standardisation's
+    eager ops."""
+    return jax.jit(_named(_stacked_one(max_iters, linsolve, newton_dtype),
+                          True))
 
 
 def _stacked_solver(axes, max_iters: int, linsolve: str, newton_dtype: str):
@@ -590,7 +831,8 @@ def _stacked_solver(axes, max_iters: int, linsolve: str, newton_dtype: str):
     iteration zero, and under the Pallas backend each Newton step of the
     whole batch is ONE blocked batched-Cholesky kernel launch."""
     def build():
-        one = _stacked_one(max_iters, linsolve, newton_dtype)
+        one = _named(_stacked_one(max_iters, linsolve, newton_dtype),
+                     _is_node(axes))
         return jax.jit(jax.vmap(one, in_axes=(None, 0) + axes))
 
     return _registered_jit((axes, max_iters, linsolve, newton_dtype), build)
@@ -612,11 +854,14 @@ def _stacked_solver_sharded(axes, max_iters: int, linsolve: str,
         one = _stacked_one(max_iters, linsolve, newton_dtype)
         vmapped = jax.vmap(one, in_axes=(None, 0) + axes)
         rspec = _row_pspec(row_axes)
-        in_specs = (PS(), rspec) + tuple(rspec if ax == 0 else PS()
-                                         for ax in axes)
-        return jax.jit(jax.shard_map(vmapped, mesh=mesh,
-                                     in_specs=in_specs,
-                                     out_specs=rspec, check_vma=False))
+        in_specs = (PS(), rspec) + jax.tree.map(
+            lambda ax: rspec if ax == 0 else PS(), axes,
+            is_leaf=lambda ax: ax is None)
+        return jax.jit(_named(jax.shard_map(vmapped, mesh=mesh,
+                                            in_specs=in_specs,
+                                            out_specs=rspec,
+                                            check_vma=False),
+                              _is_node(axes)))
 
     return _registered_jit(("sharded", axes, max_iters, linsolve,
                             newton_dtype, _mesh_key_of(mesh, row_axes)),
@@ -787,12 +1032,13 @@ def _chunk_prep(axes):
     LP array to the full batch so the compaction gather is a plain row
     permutation of the standard-form buffers."""
     def build():
-        def prep(c, a_eq, b_eq, g, h, lb, ub):
-            std = _standardise(c, a_eq, b_eq, g, h, lb, ub)
+        def prep(*arrs):
+            std = _standardise(*arrs)
             return (std.a, std.b, std.c, std.u, std.lb, std.row_scale,
                     std.col_scale)
 
-        return jax.jit(jax.vmap(prep, in_axes=axes))
+        return jax.jit(jax.vmap(_named(prep, _is_node(axes), "_prep"),
+                                in_axes=axes))
 
     return _registered_jit(("chunk-prep", axes), build)
 
@@ -828,19 +1074,21 @@ def _chunk_step_one(chunk_iters: int, max_iters: int, linsolve: str,
 
 
 def _chunk_stepper(chunk_iters: int, max_iters: int, linsolve: str,
-                   newton_dtype: str):
-    """Vmapped chunk step over a whole buffer (host-compaction mode)."""
+                   newton_dtype: str, node: bool):
+    """Vmapped chunk step over a whole buffer (host-compaction mode);
+    ``node`` names the program (:func:`_named`)."""
     def build():
         step_one = _chunk_step_one(chunk_iters, max_iters, linsolve,
                                    newton_dtype)
-        return jax.jit(jax.vmap(step_one, in_axes=(None, 0, 0, 0, 0, 0)))
+        return jax.jit(jax.vmap(_named(step_one, node, "_chunk"),
+                                in_axes=(None, 0, 0, 0, 0, 0)))
 
     return _registered_jit(("chunk-step", chunk_iters, max_iters, linsolve,
-                            newton_dtype), build)
+                            newton_dtype, node), build)
 
 
 def _chunk_merge_stepper(width: int, chunk_iters: int, max_iters: int,
-                         linsolve: str, newton_dtype: str,
+                         linsolve: str, newton_dtype: str, node: bool,
                          mesh=None, row_axes=None):
     """Fused per-width device program for in-jit compaction: gather the
     ``width``-row alive prefix of the full-batch buffers, step it, write
@@ -858,7 +1106,8 @@ def _chunk_merge_stepper(width: int, chunk_iters: int, max_iters: int,
     pure row permutation of the shard's own block), so the hot loop has
     no collectives — only the two host scalars do: the next buffer
     width must hold the LARGEST shard's survivor count (``pmax``) and
-    the trip accounting SUMS the per-shard lockstep trips (``psum``)."""
+    the trip accounting SUMS the per-shard lockstep trips (``psum``).
+    ``node`` names the program (:func:`_named`)."""
     step_one = _chunk_step_one(chunk_iters, max_iters, linsolve,
                                newton_dtype)
 
@@ -868,7 +1117,8 @@ def _chunk_merge_stepper(width: int, chunk_iters: int, max_iters: int,
         it_prev, it32_prev = prev.it, prev.it32
         out, rp_w, rd_w, mu_w = jax.vmap(
             step_one, in_axes=(None, 0, 0, 0, 0, 0))(
-            tol, a_f[idx], b_f[idx], c_f[idx], u_f[idx], prev)
+            tol, jax.tree.map(lambda f: f[idx], a_f), b_f[idx], c_f[idx],
+            u_f[idx], prev)
         carry = jax.tree.map(lambda f, pre: f.at[:width].set(pre),
                              carry, out)
         rp_f = rp_f.at[:width].set(rp_w)
@@ -895,15 +1145,16 @@ def _chunk_merge_stepper(width: int, chunk_iters: int, max_iters: int,
 
     def build():
         if mesh is None:
-            return jax.jit(merge)
+            return jax.jit(_named(merge, node, "_chunk"))
         from jax.sharding import PartitionSpec as PS
         rspec = _row_pspec(row_axes)
-        return jax.jit(jax.shard_map(
+        return jax.jit(_named(jax.shard_map(
             merge, mesh=mesh, in_specs=(PS(),) + (rspec,) * 9,
-            out_specs=(rspec,) * 5 + (PS(), PS()), check_vma=False))
+            out_specs=(rspec,) * 5 + (PS(), PS()), check_vma=False),
+            node, "_chunk"))
 
     return _registered_jit(("chunk-merge", width, chunk_iters, max_iters,
-                            linsolve, newton_dtype,
+                            linsolve, newton_dtype, node,
                             _mesh_key_of(mesh, row_axes)), build)
 
 
@@ -951,12 +1202,16 @@ def _warm_compact_ladder(widths, a_h, b_h, c_h, u_h, init_fn, step_fn,
     shape/config, ``stacked_compile_count`` is already final: compaction
     can never recompile mid-call or mid-episode."""
     for w in widths:
-        aw = jnp.asarray(np.broadcast_to(a_h[:1], (w,) + a_h.shape[1:]))
-        bw = jnp.asarray(np.broadcast_to(b_h[:1], (w,) + b_h.shape[1:]))
-        cw = jnp.asarray(np.broadcast_to(c_h[:1], (w,) + c_h.shape[1:]))
-        uw = jnp.asarray(np.broadcast_to(u_h[:1], (w,) + u_h.shape[1:]))
+        aw, bw, cw, uw = jax.tree.map(
+            lambda v: jnp.asarray(np.broadcast_to(v[:1], (w,) + v.shape[1:])),
+            (a_h, b_h, c_h, u_h))
         carry = init_fn(aw, bw, cw, uw, jnp.zeros((w,), dtype=bool))
         step_fn(tol_dev, aw, bw, cw, uw, carry)
+
+
+def _row_shapes(tree) -> tuple:
+    """Per-row shapes of a batched operator or array (a cache key)."""
+    return tuple(v.shape[1:] for v in jax.tree.leaves(tree))
 
 
 def _solve_stacked_compact(arrs, axes, batch: int, tol, active, *,
@@ -999,10 +1254,11 @@ def _solve_stacked_compact(arrs, axes, batch: int, tol, active, *,
             tol_dev, active, max_iters=max_iters, chunk_iters=chunk_iters,
             linsolve=linsolve, newton_dtype=newton_dtype, mesh=mesh,
             row_axes=row_axes)
-    step_fn = _chunk_stepper(chunk_iters, max_iters, linsolve, newton_dtype)
+    step_fn = _chunk_stepper(chunk_iters, max_iters, linsolve, newton_dtype,
+                             isinstance(a, NodeOp))
 
-    a_h, b_h, c_h, u_h = (np.asarray(v) for v in (a, b, c, u))
-    warm_key = ("host", a_h.shape[1:], chunk_iters, max_iters, linsolve,
+    a_h, b_h, c_h, u_h = jax.tree.map(np.asarray, (a, b, c, u))
+    warm_key = ("host", _row_shapes(a_h), chunk_iters, max_iters, linsolve,
                 newton_dtype, tuple(widths))
     if warm_key not in _WARMED_LADDERS:
         with obs.span("lp.warm_compact_ladder", widths=tuple(widths),
@@ -1018,8 +1274,8 @@ def _solve_stacked_compact(arrs, axes, batch: int, tol, active, *,
     it_prev = np.zeros(batch, dtype=np.int64)
     it32_prev = np.zeros(batch, dtype=np.int64)
     out = {
-        "x": np.zeros((batch, a_h.shape[2])),
-        "y": np.zeros((batch, a_h.shape[1])),
+        "x": np.zeros((batch, c_h.shape[1])),
+        "y": np.zeros((batch, b_h.shape[1])),
         "it": np.zeros(batch, dtype=np.int64),
         "it32": np.zeros(batch, dtype=np.int64),
         "bad": np.zeros(batch, dtype=bool),
@@ -1074,8 +1330,8 @@ def _solve_stacked_compact(arrs, axes, batch: int, tol, active, *,
                 # by the surviving rows' original indices, not buffer
                 # slots
                 src = orig[take]
-                cur = tuple(jnp.asarray(v[src])
-                            for v in (a_h, b_h, c_h, u_h))
+                cur = jax.tree.map(lambda v: jnp.asarray(v[src]),
+                                   (a_h, b_h, c_h, u_h))
                 orig = src
                 orig[idx.size:] = -1
                 width = w_next
@@ -1123,14 +1379,15 @@ def _compact_device(arrs, a, b, c, u, lb, rsc, csc, batch, n_orig, widths,
     local = batch // n_shards
     merge_fns = {w: _chunk_merge_stepper(w, chunk_iters, max_iters,
                                          linsolve, newton_dtype,
-                                         mesh=mesh, row_axes=row_axes)
+                                         isinstance(a, NodeOp), mesh=mesh,
+                                         row_axes=row_axes)
                  for w in widths}
     fin_fn = _chunk_finalize(n_orig, mesh=mesh, row_axes=row_axes,
                              c_batched=arrs[0].ndim == 2)
     zeros = jnp.zeros((batch,), jnp.float64)
     perm0 = jnp.asarray(np.tile(np.arange(local, dtype=np.int32), n_shards))
 
-    warm_key = ("device", tuple(a.shape[1:]), chunk_iters, max_iters,
+    warm_key = ("device", _row_shapes(a), chunk_iters, max_iters,
                 linsolve, newton_dtype, tuple(widths),
                 _mesh_key_of(mesh, row_axes))
     if warm_key not in _WARMED_LADDERS:
@@ -1194,6 +1451,14 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     MATRIX, not just the rhs).  All fields of the returned
     :class:`LPSolution` gain a leading batch axis.
 
+    Node LPs come in compact form: a
+    :class:`~repro.core.problem.NodeRows` in ``a_eq``, with ``b_eq`` and
+    ``g`` None and ``h`` the border rows' rhs.  They are solved through
+    :class:`NodeOp` (each Newton system reduced to its 2*mu+1 border) in
+    programs named :data:`NODE_IPM`; their copy to the device counts
+    in the ``lp.put_bytes`` and ``lp.put_rows`` counters, and the
+    ``lp.solve_stacked`` span gains attributes ``mu`` and ``tau``.
+
     ``linsolve`` selects the Newton normal-equation backend (see
     :data:`LINSOLVES`); with ``"pallas"`` every Newton step of the batch
     is one blocked batched-Cholesky kernel launch.  ``row_active`` is an
@@ -1247,22 +1512,33 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     chunk_iters = _CHUNK_ITERS if chunk_iters is None else int(chunk_iters)
     if chunk_iters < 1:
         raise ValueError(f"chunk_iters must be >= 1, got {chunk_iters}")
+    node = isinstance(a_eq, NodeRows)
+    bases = _base_ndims(a_eq, b_eq, g)
     with obs.span("lp.put"):
-        arrs = tuple(jnp.asarray(v, dt)
-                     for v in (c, a_eq, b_eq, g, h, lb, ub))
-    axes = tuple(0 if a.ndim == base + 1 else None
-                 for a, base in zip(arrs, _BASE_NDIM))
-    for a, base, ax in zip(arrs, _BASE_NDIM, axes):
+        arrs = _on_device((c, a_eq, b_eq, g, h, lb, ub), dt)
+    # one axis per array leaf (None args have none), then as a pytree
+    # like the arguments (vmap's in_axes)
+    leaves, treedef = jax.tree.flatten(arrs)
+    flat_axes = tuple(0 if a.ndim == base + 1 else None
+                      for a, base in zip(leaves, jax.tree.leaves(bases)))
+    for a, base, ax in zip(leaves, jax.tree.leaves(bases), flat_axes):
         if ax is None and a.ndim != base:
             raise ValueError(f"array has ndim {a.ndim}, expected {base} "
                              f"or {base + 1} (batched)")
-    if not any(ax == 0 for ax in axes):
+    if not any(ax == 0 for ax in flat_axes):
         raise ValueError("solve_lp_stacked needs at least one batched array; "
                          "use solve_lp for a single LP")
-    sizes = {a.shape[0] for a, ax in zip(arrs, axes) if ax == 0}
+    axes = treedef.unflatten(flat_axes)
+    sizes = {a.shape[0] for a, ax in zip(leaves, flat_axes) if ax == 0}
     if len(sizes) != 1:
         raise ValueError(f"inconsistent batch sizes: {sorted(sizes)}")
     (batch,) = sizes
+    span_kw = {}
+    if node:
+        obs.update(counters={"lp.put_bytes": sum(a.nbytes for a in leaves),
+                             "lp.put_rows": batch})
+        mu, tau = arrs[1].coef.shape[-2:]
+        span_kw = dict(mu=int(mu), tau=int(tau))
     if row_active is None:
         active = jnp.ones((batch,), dtype=bool)
     else:
@@ -1271,7 +1547,7 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
             raise ValueError(f"row_active shaped {active.shape}, "
                              f"expected ({batch},)")
     row_shape = tuple(a.shape[1:] if ax == 0 else a.shape
-                      for a, ax in zip(arrs, axes))
+                      for a, ax in zip(leaves, flat_axes))
     row_axes = _lp_row_axes(mesh, row_spec) if mesh is not None else None
     n_shards = _n_shards_of(mesh, row_axes)
     mesh_shape = _mesh_shape_of(mesh, row_axes)
@@ -1282,9 +1558,10 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     # does, via ladder_widths(n_shards=)).
     n_req, pad = batch, (-batch) % n_shards
     if pad:
-        arrs = tuple(jnp.concatenate(
+        leaves = [jnp.concatenate(
             [a, jnp.broadcast_to(a[:1], (pad,) + a.shape[1:])])
-            if ax == 0 else a for a, ax in zip(arrs, axes))
+            if ax == 0 else a for a, ax in zip(leaves, flat_axes)]
+        arrs = treedef.unflatten(leaves)
         active = jnp.concatenate([active, jnp.zeros((pad,), bool)])
         batch += pad
     if compact:
@@ -1297,11 +1574,11 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                 "NumPy round-trip has no sharded layout; use the default "
                 "compact_mode='device'")
         sig = ("compact", compact_mode, axes, max_iters, chunk_iters,
-               linsolve, newton_dtype, tuple(a.shape for a in arrs),
+               linsolve, newton_dtype, tuple(a.shape for a in leaves),
                mesh_key)
         if sig not in _STACKED_SIGNATURES:
             _STACKED_SIGNATURES.add(sig)
-            obs.record_compile("compact", width=batch, axes=axes,
+            obs.record_compile("compact", width=batch, axes=flat_axes,
                                max_iters=max_iters, linsolve=linsolve,
                                newton_dtype=newton_dtype, compact=True,
                                chunk_iters=chunk_iters, row_shape=row_shape,
@@ -1309,8 +1586,8 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                                mesh_shape=mesh_shape)
         with obs.span("lp.solve_stacked", width=batch, compact=True,
                       linsolve=linsolve, newton_dtype=newton_dtype,
-                      compact_mode=compact_mode, n_shards=n_shards) as sp, \
-                _partitioner(mesh):
+                      compact_mode=compact_mode, n_shards=n_shards,
+                      **span_kw) as sp, _partitioner(mesh):
             with obs.span("lp.dispatch"):
                 sol, it32, bad, compact_rows = _solve_stacked_compact(
                     arrs, axes, batch, tol, active, max_iters=max_iters,
@@ -1325,10 +1602,10 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
                     bad=bad, compact_rows=compact_rows))
         return LPSolution(*(f[:n_req] for f in sol)) if pad else sol
     sig = (axes, max_iters, linsolve, newton_dtype,
-           tuple(a.shape for a in arrs), mesh_key)
+           tuple(a.shape for a in leaves), mesh_key)
     if sig not in _STACKED_SIGNATURES:
         _STACKED_SIGNATURES.add(sig)
-        obs.record_compile("stacked", width=batch, axes=axes,
+        obs.record_compile("stacked", width=batch, axes=flat_axes,
                            max_iters=max_iters, linsolve=linsolve,
                            newton_dtype=newton_dtype, compact=False,
                            chunk_iters=None, row_shape=row_shape,
@@ -1338,7 +1615,7 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     # not lazy-dispatch time
     with obs.span("lp.solve_stacked", width=batch, compact=False,
                   linsolve=linsolve, newton_dtype=newton_dtype,
-                  n_shards=n_shards) as sp, _partitioner(mesh):
+                  n_shards=n_shards, **span_kw) as sp, _partitioner(mesh):
         solver = (_stacked_solver(axes, max_iters, linsolve, newton_dtype)
                   if mesh is None else
                   _stacked_solver_sharded(axes, max_iters, linsolve,
@@ -1354,6 +1631,10 @@ def solve_lp_stacked(c, a_eq, b_eq, g, h, lb, ub,
     return LPSolution(*(f[:n_req] for f in sol)) if pad else sol
 
 
+# the fields of a NodeLP a stacked solve copies, one row per node
+_NODE_FIELDS = ("coef", "rho", "pi", "h", "lb", "ub")
+
+
 def solve_node_lps_stacked(nodes, *, max_iters: int = _MAX_ITERS,
                            tol: float = _TOL, linsolve: str = "xla",
                            row_active=None, compact: bool = False,
@@ -1362,14 +1643,18 @@ def solve_node_lps_stacked(nodes, *, max_iters: int = _MAX_ITERS,
                            row_spec=None) -> LPSolution:
     """Stack a sequence of same-shape :class:`~repro.core.problem.NodeLP`
     relaxations (e.g. one per scenario x budget point) and solve them in a
-    single batched IPM call."""
+    single batched IPM call through :class:`NodeOp`.  Only the compact
+    fields are stacked and copied (``coef``, ``rho``, ``pi``, ``h``,
+    ``lb``, ``ub``; the objective, the same for every node, once)."""
     nodes = list(nodes)
     if not nodes:
         raise ValueError("empty node stack")
     with obs.span("lp.put"):
-        stacked = [np.stack([np.asarray(getattr(n, f)) for n in nodes])
-                   for f in ("c", "a_eq", "b_eq", "g", "h", "lb", "ub")]
-    return solve_lp_stacked(*stacked, max_iters=max_iters, tol=tol,
+        coef, rho, pi, h, lb, ub = (
+            np.stack([np.asarray(getattr(n, f), np.float64) for n in nodes])
+            for f in _NODE_FIELDS)
+    return solve_lp_stacked(nodes[0].c, NodeRows(coef, rho, pi), None, None,
+                            h, lb, ub, max_iters=max_iters, tol=tol,
                             linsolve=linsolve, row_active=row_active,
                             compact=compact, chunk_iters=chunk_iters,
                             newton_dtype=newton_dtype,
@@ -1403,11 +1688,13 @@ def stacked_attribution_key(node, *, max_iters: int = _MAX_ITERS,
     newton_dtype = _canon_newton_dtype(newton_dtype)
     chunk_iters = (_CHUNK_ITERS if chunk_iters is None
                    else int(chunk_iters)) if compact else None
-    row_shape = tuple(np.asarray(getattr(node, f)).shape
-                      for f in ("c", "a_eq", "b_eq", "g", "h", "lb", "ub"))
+    # as solve_node_lps_stacked passes them: the objective once, the
+    # compact fields one row per node
+    row_shape = ((node.lb.shape[0],),) + tuple(
+        np.shape(getattr(node, f)) for f in _NODE_FIELDS)
     return {
         "kind": "compact" if compact else "stacked",
-        "axes": (0,) * 7,
+        "axes": (None,) + (0,) * 6,
         "max_iters": int(max_iters),
         "linsolve": linsolve,
         "newton_dtype": newton_dtype,
@@ -1561,11 +1848,17 @@ def solve_lp_batched(c, a_eq, b_eq, g, h_batch, lb, ub,
 
 
 def scipy_reference_lp(c, a_eq, b_eq, g, h, lb, ub):
-    """HiGHS reference solution (oracle for tests / IPM fallback)."""
+    """HiGHS reference solution (oracle for tests / IPM fallback).  The
+    rows ``a_eq`` and ``g`` may be dense or scipy sparse matrices."""
+    import scipy.sparse as sp
     from scipy.optimize import linprog
+
+    def rows(m):
+        return m if sp.issparse(m) else np.asarray(m, float)
+
     bounds = list(zip(np.asarray(lb, float),
                       [b if np.isfinite(b) else None for b in np.asarray(ub, float)]))
-    res = linprog(np.asarray(c, float), A_ub=np.asarray(g, float),
-                  b_ub=np.asarray(h, float), A_eq=np.asarray(a_eq, float),
+    res = linprog(np.asarray(c, float), A_ub=rows(g),
+                  b_ub=np.asarray(h, float), A_eq=rows(a_eq),
                   b_eq=np.asarray(b_eq, float), bounds=bounds, method="highs")
     return res

@@ -76,8 +76,9 @@ def _solve_node(node, prefer_jax: bool = True, linsolve: str = "xla",
             return np.asarray(sol.x), float(sol.obj), "ok"
     obs.inc("milp.host_resolves")
     with obs.span("milp.host_resolve"):
-        res = lpmod.scipy_reference_lp(node.c, node.a_eq, node.b_eq,
-                                       node.g, node.h, node.lb, node.ub)
+        a_eq, g = node.sparse_rows()
+        res = lpmod.scipy_reference_lp(node.c, a_eq, node.b_eq, g, node.h,
+                                       node.lb, node.ub)
     if res.status == 2:
         return None, np.inf, "infeasible"
     if not res.success:
@@ -111,10 +112,17 @@ def _round_incumbent(problem: AllocationProblem, a: np.ndarray,
 
 def _expand_node(problem: AllocationProblem, nd: dict, x: np.ndarray,
                  obj: float, cost_cap: Optional[float], heap: list,
-                 counter) -> Tuple[Optional[np.ndarray], float, float]:
+                 counter, gap_tol: float
+                 ) -> Tuple[Optional[np.ndarray], float, float]:
     """Process a solved, un-pruned node: derive an incumbent candidate and
     push branched children.  Returns the (cand, mk, cost) incumbent
-    candidate (cand is None when rounding/repair fails)."""
+    candidate (cand is None when rounding/repair fails).
+
+    A node whose relaxation is integral within ``_FRAC_TOL`` is closed
+    only when its rounded candidate is within ``gap_tol`` of the
+    relaxation: a share under ``_FRAC_TOL`` costs the relaxation
+    ``gamma`` times the share but the candidate its whole setup, so
+    otherwise the node branches on the largest such share."""
     a, d, _ = problem.split_node_x(x)
     # rows with every setup binary fixed to 0 (root pin or branching)
     # cannot take work; keep the budget repair off them as well
@@ -138,8 +146,17 @@ def _expand_node(problem: AllocationProblem, nd: dict, x: np.ndarray,
     d_score = d_score_vec[d_i] if cost_cap is not None else 0.0
 
     if b_score <= _FRAC_TOL and d_score <= _FRAC_TOL:
-        # relaxation is integral-enough: node is solved exactly
-        return cand, mk, cost
+        if cand is None or mk <= obj * (1 + gap_tol):
+            # relaxation is integral-enough: node is solved exactly
+            return cand, mk, cost
+        # the candidate pays setups the relaxation barely charges: branch
+        # on the binary of the share that costs most of them
+        frac_b = np.where(free & (a > 0) & (a < 1),
+                          problem.gamma * a * (1.0 - a), 0.0)
+        bi, bj = np.unravel_index(int(np.argmax(frac_b)), frac_b.shape)
+        if frac_b[bi, bj] <= 0:
+            return cand, mk, cost
+        b_score, d_score = frac_b[bi, bj], 0.0
 
     if b_score >= d_score:
         for val in (1, 0):
@@ -195,35 +212,37 @@ def _seed_incumbent(problem: AllocationProblem, cost_cap: Optional[float],
     allocation when given (repaired into budget if it overshoots) — warm
     starts strengthen the seed, never replace it.  ``pinned`` is the
     root's b_fixed0 mask; platforms whose every setup binary is pinned to
-    zero (dead/empty slots) are stripped from every candidate."""
-    incumbent, inc_mk, inc_cost = None, np.inf, np.inf
-    allowed = None
-    if pinned is not None:
-        allowed = ~np.asarray(pinned, bool).all(axis=1)
-    if cost_cap is None:
-        cand = heuristics.proportional_split(problem)
-        cand_list = [cand, heuristics.min_min(problem)]
-    else:
-        cand_list = []
-        h = heuristics.best_heuristic_for_budget(problem, cost_cap)
-        if h is not None:
-            cand_list.append(h)
-    if warm_alloc is not None:
-        cand_list.append(_project_to_allocation(problem, warm_alloc,
-                                                allowed))
-    for cand in cand_list:
-        if allowed is not None:
-            cand = _project_to_allocation(problem, cand, allowed)
-        mk, cost = heuristics.evaluate(problem, cand)
-        if cost_cap is not None and cost > cost_cap * (1 + _FEAS_TOL):
-            cand = heuristics.repair_to_budget(problem, cand, cost_cap,
-                                               allowed=allowed)
-            if cand is None:
-                continue
+    zero (dead/empty slots) are stripped from every candidate.  Each call
+    is one ``milp.seed`` span."""
+    with obs.span("milp.seed"):
+        incumbent, inc_mk, inc_cost = None, np.inf, np.inf
+        allowed = None
+        if pinned is not None:
+            allowed = ~np.asarray(pinned, bool).all(axis=1)
+        if cost_cap is None:
+            cand = heuristics.proportional_split(problem)
+            cand_list = [cand, heuristics.min_min(problem)]
+        else:
+            cand_list = []
+            h = heuristics.best_heuristic_for_budget(problem, cost_cap)
+            if h is not None:
+                cand_list.append(h)
+        if warm_alloc is not None:
+            cand_list.append(_project_to_allocation(problem, warm_alloc,
+                                                    allowed))
+        for cand in cand_list:
+            if allowed is not None:
+                cand = _project_to_allocation(problem, cand, allowed)
             mk, cost = heuristics.evaluate(problem, cand)
-        if (cost_cap is None or cost <= cost_cap * (1 + _FEAS_TOL)) and mk < inc_mk:
-            incumbent, inc_mk, inc_cost = cand, mk, cost
-    return incumbent, inc_mk, inc_cost
+            if cost_cap is not None and cost > cost_cap * (1 + _FEAS_TOL):
+                cand = heuristics.repair_to_budget(problem, cand, cost_cap,
+                                                   allowed=allowed)
+                if cand is None:
+                    continue
+                mk, cost = heuristics.evaluate(problem, cand)
+            if (cost_cap is None or cost <= cost_cap * (1 + _FEAS_TOL)) and mk < inc_mk:
+                incumbent, inc_mk, inc_cost = cand, mk, cost
+        return incumbent, inc_mk, inc_cost
 
 
 def solve_bnb(problem: AllocationProblem, cost_cap: Optional[float] = None,
@@ -289,7 +308,7 @@ def solve_bnb(problem: AllocationProblem, cost_cap: Optional[float] = None,
         if obj >= inc_mk * (1 - gap_tol):
             continue
         cand, mk, cost = _expand_node(problem, nd, x, obj, cost_cap,
-                                      heap, counter)
+                                      heap, counter, gap_tol)
         if cand is not None and mk < inc_mk:
             incumbent, inc_mk, inc_cost = cand, mk, cost
 
@@ -330,7 +349,7 @@ def _warm_rungs(node, rungs: list, **solver) -> None:
     """Compile every rung for ``node``'s shape and ``solver``
     configuration, once per process, with all-retired calls
     (:func:`repro.core.lp.warm_ladder`)."""
-    key = (tuple(np.shape(v) for v in node), tuple(rungs),
+    key = (np.shape(node.coef), np.shape(node.h), tuple(rungs),
            tuple(sorted(solver.items())))
     if key not in _WARM_RUNGS:
         lpmod.warm_ladder(node, rungs[0], widths=rungs, **solver)
@@ -561,7 +580,7 @@ def solve_bnb_sweep(problem: AllocationProblem, caps,
                         continue
                     cand, mk, cost = _expand_node(problem, nd, x, obj,
                                                   tr["cap"], tr["heap"],
-                                                  tr["counter"])
+                                                  tr["counter"], gap_tol)
                     if cand is not None and mk < tr["inc_mk"]:
                         tr["incumbent"], tr["inc_mk"], tr["inc_cost"] = \
                             cand, mk, cost

@@ -137,10 +137,11 @@ def relaxation_frontier(problem: AllocationProblem, caps: np.ndarray,
     from repro.core import lp as lpmod
     caps = np.asarray(caps, dtype=np.float64)
     node = problem.node_lp(cost_cap=float(caps[0]))
-    # cost row is the LAST inequality row by construction
+    # cost row is the LAST inequality row by construction; only the rhs
+    # is batched, so the node's rows are copied once
     h_batch = np.tile(np.asarray(node.h), (len(caps), 1))
     h_batch[:, -1] = caps
-    sols = lpmod.solve_lp_stacked(node.c, node.a_eq, node.b_eq, node.g,
+    sols = lpmod.solve_lp_stacked(node.c, node.rows, None, None,
                                   h_batch, node.lb, node.ub,
                                   linsolve=linsolve, compact=compact,
                                   chunk_iters=chunk_iters,
@@ -259,12 +260,7 @@ def frontier_nodes(problem: AllocationProblem, caps,
                          f"got shape {caps.shape}")
     b0 = dead_pin_mask(dead, problem.tau) if dead is not None else None
     base = problem.node_lp(cost_cap=float(caps[0]), b_fixed0=b0)
-    nodes = []
-    for ck in caps:
-        h = np.array(base.h)
-        h[-1] = float(ck)
-        nodes.append(base._replace(h=h))
-    return nodes
+    return [base._replace(cap=float(ck)) for ck in caps]
 
 
 @dataclasses.dataclass

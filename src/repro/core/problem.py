@@ -7,7 +7,8 @@ solver-ready forms:
   relaxation used by our B&B (DESIGN.md §2): the binary setup matrix B and
   the integer quanta vector D are substituted out of the relaxation
   (B* = A, D* = G_L/rho at any LP optimum), so a node LP has only
-  (A, D, F_L) variables and ~tau + 2*mu + 1 rows.
+  (A, D, F_L) variables and ~tau + 2*mu + 1 rows.  It is held compact
+  (:class:`NodeLP`): the latency coefficients, bounds and budget, O(mu*tau).
 
 * :meth:`AllocationProblem.full_milp_arrays` — the untransformed Eq. 4
   (A real, B binary, D integer, F_L real) as dense arrays for
@@ -23,18 +24,99 @@ import numpy as np
 BIG_M_SLACK = 1.0 + 1e-9
 
 
-class NodeLP(NamedTuple):
-    """Dense LP:  min c.x  s.t.  A_eq x = b_eq,  G x <= h,  lb <= x <= ub.
+class NodeRows(NamedTuple):
+    """The constraint rows of a node LP, held by their structure.
 
-    Variable layout: x = [A.ravel() (mu*tau), D (mu), F_L (1)].
+    One row per task j places it, ``sum_i A_ij = 1``; then, per platform
+    i, a latency row ``coef_i . A_i - F_L`` and a quanta row ``coef_i .
+    A_i - rho_i D_i``; then, with a budget, ``pi . D``.  Each leaf may
+    carry a leading batch axis (a stack of node LPs)."""
+    coef: np.ndarray            # (mu, tau)
+    rho: np.ndarray             # (mu,)
+    pi: np.ndarray              # (mu,)
+
+
+class NodeLP(NamedTuple):
+    """A B&B node's LP relaxation in compact form:
+
+        min F_L  s.t.  sum_i A_ij = 1                      (each task j)
+                       coef_i . A_i - F_L       <= -const_i  (latency)
+                       coef_i . A_i - rho_i D_i <= -const_i  (quanta)
+                       pi . D                   <= cap       (budget)
+                       lb <= x <= ub
+
+    Variable layout: x = [A.ravel() (mu*tau), D (mu), F_L (1)].  With
+    ``cap`` None the budget row is left out.  The dense view
+    ``min c.x  s.t.  A_eq x = b_eq,  G x <= h`` is built on read
+    (``c``, ``a_eq``, ``b_eq``, ``g``, ``h``); the solver never builds
+    it (:func:`repro.core.lp.solve_node_lps_stacked` takes ``rows``).
     """
-    c: np.ndarray
-    a_eq: np.ndarray
-    b_eq: np.ndarray
-    g: np.ndarray
-    h: np.ndarray
-    lb: np.ndarray
-    ub: np.ndarray
+    coef: np.ndarray            # (mu, tau) latency coefficient of A
+    const: np.ndarray           # (mu,) latency of setups fixed to 1
+    rho: np.ndarray             # (mu,)
+    pi: np.ndarray              # (mu,)
+    cap: Optional[float]
+    lb: np.ndarray              # (n,)
+    ub: np.ndarray              # (n,)
+
+    @property
+    def rows(self) -> NodeRows:
+        return NodeRows(self.coef, self.rho, self.pi)
+
+    @property
+    def c(self) -> np.ndarray:
+        c = np.zeros(self.lb.shape[0])
+        c[-1] = 1.0
+        return c
+
+    @property
+    def b_eq(self) -> np.ndarray:
+        return np.ones(self.coef.shape[1])
+
+    @property
+    def h(self) -> np.ndarray:
+        cap = [] if self.cap is None else [float(self.cap)]
+        return np.concatenate([-self.const, -self.const, cap])
+
+    @property
+    def a_eq(self) -> np.ndarray:
+        return self.sparse_rows()[0].toarray()
+
+    @property
+    def g(self) -> np.ndarray:
+        return self.sparse_rows()[1].toarray()
+
+    def sparse_rows(self):
+        """(A_eq, G) as scipy CSR matrices: the rows a host LP solver
+        takes, laid out by :func:`node_border` as the solver's are."""
+        import scipy.sparse as sp
+        mu, tau = self.coef.shape
+        p, small = node_border(np.asarray(self.rho, float),
+                               np.asarray(self.pi, float),
+                               2 * mu + (self.cap is not None))
+        a_eq = sp.hstack([sp.kron(np.ones((1, mu)), sp.eye(tau)),
+                          sp.csr_matrix((tau, mu + 1))])
+        lat = sp.block_diag([row[None, :] for row in self.coef])
+        g = sp.hstack([sp.csr_matrix(p) @ lat, sp.csr_matrix(small)])
+        return a_eq.tocsr(), g.tocsr()
+
+
+def node_border(rho, pi, m_b: int, xp=np):
+    """The border rows of a node LP, ``(p, small)``: ``m_b`` is 2*mu
+    (latency rows, then quanta rows) or 2*mu + 1 (then the budget row).
+    Border row k weighs platform i's latency sum ``coef_i . A_i`` by
+    ``p[k, i]`` and the columns D (mu) and F_L (1) by ``small[k]``.
+    ``xp`` is numpy or jax.numpy: the host's sparse rows and the
+    solver's operator are built from this one description."""
+    mu = rho.shape[-1]
+    dt = rho.dtype
+    eye = xp.eye(mu, dtype=dt)
+    p = xp.concatenate([eye, eye, xp.zeros((m_b - 2 * mu, mu), dt)])
+    d_cols = xp.concatenate([xp.zeros((mu, mu), dt), -xp.diag(rho),
+                             pi[None, :].astype(dt)])[:m_b]
+    f_col = xp.concatenate([-xp.ones((mu, 1), dt),
+                            xp.zeros((mu + 1, 1), dt)])[:m_b]
+    return p, xp.concatenate([d_cols, f_col], axis=1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,21 +208,6 @@ class AllocationProblem:
         if (b_fixed0 & b_fixed1).any():
             raise ValueError("a binary cannot be fixed to both 0 and 1")
         n_a = mu * tau
-        n_x = n_a + mu + 1           # A, D, F_L
-        idx_d = n_a
-        idx_f = n_a + mu
-
-        c = np.zeros(n_x)
-        c[idx_f] = 1.0
-
-        # sum_i A_ij = 1 for each task j
-        a_eq = np.zeros((tau, n_x))
-        for j in range(tau):
-            # A raveled as (mu, tau): element (i, j) at i*tau + j.  Slice
-            # must stop at n_a (the D / F_L columns follow).
-            a_eq[j, j:n_a:tau] = 1.0
-        b_eq = np.ones(tau)
-
         # latency coefficient for A_ij in G_L,i:
         #   free binary    -> (beta_n + gamma) * A   (relaxed B = A)
         #   fixed to 1     -> beta_n * A + gamma (constant)
@@ -148,40 +215,15 @@ class AllocationProblem:
         coef = self.beta_n + np.where(b_fixed1 | b_fixed0, 0.0, self.gamma)
         const = (self.gamma * b_fixed1).sum(axis=1)    # (mu,)
 
-        rows = []
-        rhs = []
-        # G_L,i - F_L <= 0   ->  coef_i . A - F_L <= -const_i
-        for i in range(mu):
-            row = np.zeros(n_x)
-            row[i * tau:(i + 1) * tau] = coef[i]
-            row[idx_f] = -1.0
-            rows.append(row)
-            rhs.append(-const[i])
-        # G_L,i - rho_i * D_i <= 0
-        for i in range(mu):
-            row = np.zeros(n_x)
-            row[i * tau:(i + 1) * tau] = coef[i]
-            row[idx_d + i] = -self.rho[i]
-            rows.append(row)
-            rhs.append(-const[i])
-        # cost: pi . D <= C_k
-        if cost_cap is not None:
-            row = np.zeros(n_x)
-            row[idx_d:idx_d + mu] = self.pi
-            rows.append(row)
-            rhs.append(float(cost_cap))
-        g = np.stack(rows)
-        h = np.asarray(rhs)
-
-        lb = np.zeros(n_x)
-        ub = np.full(n_x, np.inf)
-        a_ub = np.where(b_fixed0, 0.0, 1.0).ravel()
-        ub[:n_a] = a_ub
+        lb = np.zeros(n_a + mu + 1)
+        ub = np.full(n_a + mu + 1, np.inf)
+        ub[:n_a] = np.where(b_fixed0, 0.0, 1.0).ravel()
         dmax = self.d_max()
-        ub[idx_d:idx_d + mu] = dmax if d_ub is None else np.minimum(d_ub, dmax)
+        ub[n_a:n_a + mu] = dmax if d_ub is None else np.minimum(d_ub, dmax)
         if d_lb is not None:
-            lb[idx_d:idx_d + mu] = d_lb
-        return NodeLP(c, a_eq, b_eq, g, h, lb, ub)
+            lb[n_a:n_a + mu] = d_lb
+        return NodeLP(coef, const, self.rho, self.pi,
+                      None if cost_cap is None else float(cost_cap), lb, ub)
 
     def split_node_x(self, x: np.ndarray) -> Tuple[np.ndarray, np.ndarray, float]:
         """x -> (A (mu,tau), D (mu,), F_L)."""

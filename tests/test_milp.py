@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from repro.core import heuristics, milp
+from repro.core import heuristics, lp, milp
 from repro.core.problem import AllocationProblem
 
 
@@ -465,3 +465,71 @@ def test_width_one_sweep_compiles_one_width():
     assert {e.attrs["width"] for e in events
             if e.name == "lp.solve_stacked"} == {1}
     assert not any(e.name == "lp.warm_width" for e in events)
+
+
+# ---------------------------------------------------------------------------
+# Nodes integral within _FRAC_TOL, the host re-solve
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [2147484425, 2147507104])
+def test_node_with_near_zero_shares_is_branched_not_closed(seed):
+    """A relaxation whose shares all lie within _FRAC_TOL of 0 or 1 can
+    still charge a share under 1e-6 only gamma times the share, while its
+    rounded candidate pays the whole setup: such a node is branched, and
+    both B&B engines reach HiGHS's optimum on the benchmark's tiny
+    configuration (seeds where they used to stop above it)."""
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from bench import data
+    from tests.bench.tinybench import tiny_config
+    m = data.tenant_models(tiny_config(), seed)[0]
+    p = AllocationProblem(m["beta"], m["gamma"], m["n"], m["rho"], m["pi"])
+    cap, gap_tol = 0.658, 1e-4
+    ref = milp.solve_highs(p, cap)
+    kw = dict(node_limit=5000, time_limit_s=np.inf, gap_tol=gap_tol)
+    for res in (milp.solve_bnb(p, cap, **kw),
+                milp.solve_bnb_sweep(p, [cap], **kw)[0]):
+        assert res.status == "optimal"
+        assert res.makespan <= ref.makespan * (1 + gap_tol)
+
+
+def test_host_resolve_builds_sparse_rows(monkeypatch):
+    """The HiGHS fallback of a node solve takes the node's sparse rows
+    and never builds the dense ones."""
+    from repro.core.problem import NodeLP
+    p = random_problem(12, mu=3, tau=5)
+    node = p.node_lp(float(p.single_platform_cost().min() * 2))
+    dense = lp.scipy_reference_lp(node.c, node.a_eq, node.b_eq, node.g,
+                                  node.h, node.lb, node.ub)
+
+    def refuse(self):
+        raise AssertionError("dense node rows built")
+
+    monkeypatch.setattr(NodeLP, "a_eq", property(refuse))
+    monkeypatch.setattr(NodeLP, "g", property(refuse))
+    x, obj, status = milp._solve_node(node, prefer_jax=False)
+    assert status == "ok"
+    assert abs(obj - dense.fun) <= 1e-9 * abs(dense.fun)
+    np.testing.assert_allclose(x, dense.x, atol=1e-9)
+
+
+def test_host_resolve_counts_each_node_and_tells_infeasible_apart():
+    """A node the stacked solve leaves unconverged goes to HiGHS on its
+    sparse rows: each re-solve counts once in ``milp.host_resolves``, an
+    empty node reads infeasible, and a feasible one matches the dense
+    rows' answer."""
+    from repro import obs
+    p = random_problem(13, mu=3, tau=6)
+    c_l = float(p.single_platform_cost().min())
+    nodes = [p.node_lp(c_l * f) for f in (0.5, 1.2, 2.0, 3.0, 0.2)]
+    with obs.scope() as scoped:
+        got = [milp._solve_node(n, prefer_jax=False) for n in nodes]
+    assert scoped["counters"]["milp.host_resolves"] == len(nodes)
+    assert {st for _, _, st in got} == {"ok", "infeasible"}
+    for n, (x, obj, st) in zip(nodes, got):
+        if st == "ok":
+            dense = lp.scipy_reference_lp(n.c, n.a_eq, n.b_eq, n.g, n.h,
+                                          n.lb, n.ub)
+            assert abs(obj - dense.fun) <= 1e-9 * abs(dense.fun)

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import lp
-from repro.core.problem import AllocationProblem
+from repro.core.problem import AllocationProblem, NodeRows
 from repro.kernels import batched_chol as bc
 from repro.kernels import mc_pricing, ops
 from repro.pricing.options import KIND_IDS, N_PARAM_COLS
@@ -101,6 +101,56 @@ def test_stacked_ipm_default_backend_compiles_at_width_32(one_chip):
     mem = compiled.memory_analysis()
     # the whole program has to fit one chip's 16 GB of HBM
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def _node_args(sharding, mu, tau, width):
+    """Argument shapes of the node-LP program at one dispatch width: the
+    objective once, each node's compact fields stacked."""
+    rng = np.random.default_rng(0)
+    p = AllocationProblem(rng.uniform(1e-6, 1e-4, (mu, tau)),
+                          rng.uniform(0.1, 5.0, (mu, tau)),
+                          rng.uniform(1e5, 1e7, tau),
+                          rng.uniform(60, 600, mu),
+                          rng.uniform(0.01, 0.1, mu))
+    node = p.node_lp(cost_cap=1e4)
+    f64 = jnp.float64
+
+    def rows(f):
+        return jax.ShapeDtypeStruct((width,) + np.shape(getattr(node, f)),
+                                    f64, sharding=sharding)
+
+    return [jax.ShapeDtypeStruct((), f64, sharding=sharding),
+            jax.ShapeDtypeStruct((width,), jnp.bool_, sharding=sharding),
+            jax.ShapeDtypeStruct(node.lb.shape, f64, sharding=sharding),
+            NodeRows(rows("coef"), rows("rho"), rows("pi")), None, None,
+            rows("h"), rows("lb"), rows("ub")]
+
+
+def _node_program(linsolve, newton_dtype):
+    """A fresh jit(vmap) of the node-LP program the lockstep B&B and the
+    server dispatch (objective unbatched, compact fields batched)."""
+    one = lp._stacked_one(lp._MAX_ITERS, linsolve, newton_dtype)
+    return jax.jit(jax.vmap(one, in_axes=(None, 0, None, NodeRows(0, 0, 0),
+                                          None, None, 0, 0, 0)))
+
+
+@pytest.mark.parametrize("tau", [TAU, 4096])
+def test_node_ipm_compiles_at_width_16(one_chip, tau):
+    """The structured node-LP program at the B&B's top rung, at the
+    paper's 128 tasks and at a 4,096-option book: it fits one chip, and
+    holds no dense node matrix (at 4,096 tasks the normal matrices alone
+    would be 16 x 136 MB, the equality rows 16 x 2.15 GB)."""
+    compiled = _node_program("xla", "float64").lower(
+        *_node_args(one_chip, MU, tau, 16)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1e9
+
+
+def test_node_ipm_pallas_float32_compiles_the_kernel(one_chip,
+                                                     compiled_kernels):
+    compiled = _node_program("pallas", "float32").lower(
+        *_node_args(one_chip, MU, TAU, 16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_stacked_ipm_pallas_float32_compiles_the_kernel(one_chip,
